@@ -10,7 +10,6 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs import ARCHS, get_config
 from repro.launch.mesh import make_mesh
 from repro.models import model as model_mod
@@ -28,7 +27,7 @@ def main():
     mesh = make_mesh((len(jax.devices()), 1), ("data", "model"))
     max_len = args.prompt_len + args.gen
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model_mod.init_params(cfg, jax.random.PRNGKey(0))
         cache = model_mod.init_cache(cfg, args.batch, max_len)
         cache_bytes = sum(l.nbytes for l in jax.tree.leaves(cache))

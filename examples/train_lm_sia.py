@@ -15,7 +15,6 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.core.algorithms import AggConfig, AggKind
 from repro.data.synthetic import lm_batch, make_bigram_lm
@@ -51,7 +50,7 @@ def main():
                      q_frac=args.q_frac, agg_dtype="float32",
                      ef_dtype="float32", lr_warmup=20)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = jax.device_put(
             init_state(cfg, tc, mesh, jax.random.PRNGKey(0)),
             state_shardings(cfg, tc, mesh))

@@ -178,7 +178,7 @@ def _send_static(cfg: AggConfig, payload: Array, seg: int, axis,
     """
     if shift == 0:
         return payload
-    perm = _shift_perm(compat.axis_size(axis), shift)
+    perm = _shift_perm(jax.lax.axis_size(axis), shift)
     if not compact:
         return jax.lax.ppermute(payload, axis, perm)
     lead = payload.ndim - 1
@@ -205,7 +205,7 @@ def _route_butterfly(cfg: AggConfig, payload: Array, offsets: Array,
     cohorts) and each butterfly round remains ONE ppermute of the whole
     bundle for all B cohorts.
     """
-    K = compat.axis_size(axis)
+    K = jax.lax.axis_size(axis)
     rounds = max(1, math.ceil(math.log2(K))) if K > 1 else 0
     lead = payload.ndim - 1
     if compact:
@@ -257,7 +257,7 @@ def _run_chain_register(cfg, plan, flat_local, ef_local, weight, *, axis,
     a full f32 upcast here would materialize 2× the gradient shard);
     per-segment slices are upcast to f32 inside the loop.
     """
-    K = compat.axis_size(axis)
+    K = jax.lax.axis_size(axis)
     r = jax.lax.axis_index(axis)
     n = flat_local.shape[0]
     seg = n // K
@@ -332,7 +332,7 @@ def run_plan_segments_local(
     with ``(participate·alive)`` and ``q_budget`` relabeled by the
     segment's rotation — see tests/test_device_plan.py).
     """
-    K = compat.axis_size(axis)
+    K = jax.lax.axis_size(axis)
     if plan.num_clients != K:
         raise ValueError(
             f"plan has {plan.num_clients} clients but the mesh axis "
@@ -489,7 +489,7 @@ def run_plan_clients_local(
     single-sink plans, ``[R, d]`` sink-ordered for forest plans (the
     stage form of a :class:`~repro.agg.nested.NestedPlan`).
     """
-    K = compat.axis_size(axis)
+    K = jax.lax.axis_size(axis)
     if plan.num_clients != K:
         raise ValueError(
             f"plan has {plan.num_clients} clients but the mesh axis "
@@ -670,7 +670,7 @@ def _run_chain_register_batched(cfg, plan, flat_local, ef_local, weight, *,
     ONE ``ppermute`` of the ``[B, seg]`` register — the collective count of
     the sequential ring, whatever B is.
     """
-    K = compat.axis_size(axis)
+    K = jax.lax.axis_size(axis)
     r = jax.lax.axis_index(axis)
     b_coh, n = flat_local.shape
     seg = n // K
@@ -731,7 +731,7 @@ def run_plan_segments_batched(
         raise ValueError("the batched segments kernel runs one shared "
                          "plan; stacked per-cohort plans are a host "
                          "(execute_batched) feature")
-    K = compat.axis_size(axis)
+    K = jax.lax.axis_size(axis)
     if plan.num_clients != K:
         raise ValueError(
             f"plan has {plan.num_clients} clients but the mesh axis "
@@ -875,7 +875,7 @@ def run_plan_clients_batched(
     aggregates ``[B, d]`` (or ``[B, R, d]``), EF ``[B, d]``, and this
     rank's per-cohort :class:`HopStats` (``[B]`` leaves).
     """
-    K = compat.axis_size(axis)
+    K = jax.lax.axis_size(axis)
     if plan.num_clients != K:
         raise ValueError(
             f"plan has {plan.num_clients} clients but the mesh axis "
@@ -1094,7 +1094,7 @@ def run_nested_segments_local(
     if len(stage_ef_local) != n_stages - 1:
         raise ValueError(f"need {n_stages - 1} stage-EF slices, got "
                          f"{len(stage_ef_local)}")
-    sizes = [compat.axis_size(a) for a in axes]
+    sizes = [jax.lax.axis_size(a) for a in axes]
     if nested.num_clients != int(np.prod(sizes)):
         raise ValueError(
             f"nested plan has {nested.num_clients} clients but the axes "

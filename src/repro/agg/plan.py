@@ -240,6 +240,16 @@ class RoundResult(NamedTuple):
     stats: HopStats       # per-hop stats, leaves [K] (client index order)
 
 
+def _take_rows(x: Array, ids: Array) -> Array:
+    """``x[ids]`` for a [R, d] array and a [n] index vector, as n row slices.
+
+    The TPU compiler splits a gather of whole rows into d/32768 unrolled
+    pieces (512 at d=2^24), which costs seconds of compile time per
+    gather; a dynamic row slice is one copy."""
+    return jnp.stack([jax.lax.dynamic_index_in_dim(x, ids[i], keepdims=False)
+                      for i in range(ids.shape[0])])
+
+
 def execute(
     cfg: AggConfig,
     plan: AggPlan,
@@ -287,7 +297,8 @@ def execute(
     def body(inbox, xs):
         ids, mask, par = xs
         gamma_out, e_new, stats = lvl(
-            g_ext[ids], inbox[ids], e_ext[ids], w_ext[ids], p_ext[ids],
+            _take_rows(g_ext, ids), _take_rows(inbox, ids),
+            _take_rows(e_ext, ids), w_ext[ids], p_ext[ids],
             global_mask, None if q_ext is None else q_ext[ids], mask)
         inbox = inbox.at[par].add(gamma_out * mask[:, None])
         return inbox, (e_new, stats)
@@ -303,7 +314,7 @@ def execute(
 
     # scan outputs are [L, W, ...] in schedule order → client index order
     pos = jnp.asarray(plan.flat_pos)
-    e_new = e_lvl.reshape(-1, d)[pos]
+    e_new = _take_rows(e_lvl.reshape(-1, d), pos)
     stats = jax.tree.map(
         lambda s: s.reshape((-1,) + s.shape[2:])[pos], st_lvl)
     agg = inbox[k] if r_sinks == 1 else inbox[k:k + r_sinks]

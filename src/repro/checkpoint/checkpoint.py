@@ -28,8 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
-
 
 _NP_SAVABLE = {"float64", "float32", "float16", "int64", "int32", "int16",
                "int8", "uint8", "uint16", "uint32", "uint64", "bool"}
@@ -44,7 +42,7 @@ def _savable(arr: np.ndarray) -> np.ndarray:
 
 
 def _flatten_with_paths(tree: Any):
-    flat, treedef = compat.tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     paths = ["/".join(str(getattr(k, "key", getattr(k, "idx", k)))
                       for k in path) for path, _ in flat]
     leaves = [leaf for _, leaf in flat]

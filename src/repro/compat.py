@@ -1,111 +1,28 @@
-"""Compatibility shims for jax API drift (0.4.x ↔ 0.6+).
+"""Mesh and shard_map helpers shared by the executors, launchers and tests.
 
-The repo targets the modern surface (``jax.shard_map``,
-``jax.sharding.AxisType``, ``check_vma``); older runtimes (0.4.x) expose
-``jax.experimental.shard_map`` with ``check_rep`` and meshes without axis
-types. These helpers pick whichever exists so tests and examples run on
-both.
+Every mesh in this repo has ``Auto`` axes; each shard_map names its manual
+axes (``axis_names``) and leaves the rest to the partitioner. Bodies are
+written without replication checking, so ``check_vma`` is off throughout.
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where supported."""
+    """``jax.make_mesh`` with every axis ``Auto``."""
     shape, axes = tuple(shape), tuple(axes)
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    except (ImportError, TypeError):
-        return jax.make_mesh(shape, axes)
-
-
-def axis_size(axis) -> int:
-    """Static mesh-axis size inside shard_map on any jax version.
-
-    ``lax.psum(1, axis)`` of the literal 1 constant-folds to the axis size
-    as a Python int on versions predating ``jax.lax.axis_size``.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)
-
-
-def set_mesh(mesh):
-    """Ambient-mesh context manager on any jax version.
-
-    Modern jax exposes ``jax.set_mesh(mesh)`` as a context manager; on
-    0.4.x the ``Mesh`` object itself is the context manager that installs
-    the ambient mesh, so we hand it back unchanged.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def tree_flatten_with_path(tree):
-    """``jax.tree.flatten_with_path`` (0.6+) / ``jax.tree_util`` (0.4.x)."""
-    if hasattr(jax.tree, "flatten_with_path"):
-        return jax.tree.flatten_with_path(tree)
-    return jax.tree_util.tree_flatten_with_path(tree)
-
-
-def abstract_mesh():
-    """The ambient mesh, or None outside any mesh context.
-
-    0.6+ tracks an abstract mesh (``jax.sharding.get_abstract_mesh``);
-    0.4.x tracks the physical mesh installed by the ``with mesh:`` context.
-    Callers must treat axis types as Auto when the mesh doesn't carry them.
-    """
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        return get()
-    try:
-        from jax._src.mesh import thread_resources
-        mesh = thread_resources.env.physical_mesh
-        return None if mesh.empty else mesh
-    except Exception:
-        return None
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None):
-    """shard_map (replication checking off) on any jax.
+    """``jax.shard_map`` with replication checking off.
 
-    ``axis_names`` selects the *manual* axes (the 0.6+ vocabulary); axes
-    not named stay automatic inside the body. The 0.4.x fallback expresses
-    the same split through ``auto=`` (its complement).
+    ``axis_names`` selects the manual axes; axes not named stay automatic
+    inside the body. None makes every mesh axis manual.
     """
-    auto = (frozenset(mesh.axis_names) - frozenset(axis_names)
-            if axis_names is not None else frozenset())
-    if hasattr(jax, "shard_map"):
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        try:
-            return jax.shard_map(f, **kwargs)
-        except TypeError as exc:
-            if auto:
-                # dropping axis_names would run the auto axes as manual —
-                # missing collectives inside the body, silently wrong
-                raise NotImplementedError(
-                    f"this jax's shard_map has no axis_names support "
-                    f"(needed for auto axes {sorted(auto)})") from exc
-            kwargs.pop("axis_names", None)
-            return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _sm
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    if auto:
-        try:
-            return _sm(f, auto=auto, **kwargs)
-        except TypeError as exc:
-            # running auto axes as manual would silently change the
-            # body's semantics (missing collectives) — fail loudly
-            raise NotImplementedError(
-                f"this jax's shard_map has no partial-auto support "
-                f"(needed for auto axes {sorted(auto)})") from exc
-    return _sm(f, **kwargs)
+    kwargs = {} if axis_names is None else {"axis_names": axis_names}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False, **kwargs)

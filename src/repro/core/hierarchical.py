@@ -39,7 +39,6 @@ from typing import NamedTuple, Optional
 
 import jax
 
-from repro import compat
 from repro.core.algorithms import AggConfig
 from repro.core.ring import RingStats
 
@@ -86,8 +85,8 @@ def hierarchical_ring_local(
     """
     from repro.agg.device import run_nested_segments_local
 
-    nested = _pod_ring_nested_cached(compat.axis_size(pod_axis),
-                                     compat.axis_size(data_axis))
+    nested = _pod_ring_nested_cached(jax.lax.axis_size(pod_axis),
+                                     jax.lax.axis_size(data_axis))
     seg2, ef_new, (pod_ef_new,), (st1, st2) = run_nested_segments_local(
         cfg, nested, flat_local, ef_local, (pod_ef_local,), weight,
         axes=(data_axis, pod_axis), global_mask_local=global_mask_local,

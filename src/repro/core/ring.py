@@ -33,7 +33,6 @@ from typing import Any, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 
 Array = jax.Array
 
@@ -76,7 +75,7 @@ def rotated_ring_local(
     # function-level import: repro.agg.device imports RingStats from here
     from repro.agg.device import ring_chain_plan, run_plan_segments_local
 
-    K = compat.axis_size(axis)
+    K = jax.lax.axis_size(axis)
     return run_plan_segments_local(
         cfg, ring_chain_plan(K), flat_local, ef_local, weight, axis=axis,
         global_mask_local=global_mask_local, participate=participate,

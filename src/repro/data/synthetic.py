@@ -53,24 +53,35 @@ def make_synthetic_mnist(key, n: int, *, num_classes: int = 10,
 
 
 class BigramLM(NamedTuple):
-    trans: Array   # [V, V] row-stochastic transition logits
+    key: Array            # row t of the transition logits is drawn from
+    vocab: int            # fold_in(key, t): the [V, V] table is never held
+    concentration: float
 
 
 def make_bigram_lm(key, vocab: int, *, concentration: float = 3.0
                    ) -> BigramLM:
-    """Random sparse-ish bigram transition table (fixed by seed)."""
-    logits = jax.random.normal(key, (vocab, vocab)) * concentration
-    return BigramLM(trans=logits)
+    """Random sparse-ish bigram language (fixed by seed).
+
+    The [V, V] transition logits are drawn row by row as tokens need them:
+    at a published vocabulary (50,280) the whole table would take 10 GB."""
+    return BigramLM(key=key, vocab=vocab, concentration=concentration)
+
+
+def transition_logits(lm: BigramLM, tok: Array) -> Array:
+    """Logit rows [..., V] of the current tokens ``tok`` [...]."""
+    row = lambda t: jax.random.normal(jax.random.fold_in(lm.key, t),
+                                      (lm.vocab,))
+    flat = jax.vmap(row)(tok.reshape(-1)) * lm.concentration
+    return flat.reshape(tok.shape + (lm.vocab,))
 
 
 def sample_bigram(lm: BigramLM, key, batch: int, seq: int) -> Array:
     """Sample token sequences [B, S+1] from the bigram chain."""
-    v = lm.trans.shape[0]
     k0, kseq = jax.random.split(key)
-    first = jax.random.randint(k0, (batch,), 0, v)
+    first = jax.random.randint(k0, (batch,), 0, lm.vocab)
 
     def step(tok, k):
-        nxt = jax.random.categorical(k, lm.trans[tok], axis=-1)
+        nxt = jax.random.categorical(k, transition_logits(lm, tok), axis=-1)
         return nxt, nxt
 
     keys = jax.random.split(kseq, seq)

@@ -219,10 +219,36 @@ class Simulator:
         flat = flatten_lr(lr_init(self.pc))
         stage_ef = (() if self._nested is None
                     else zero_stage_ef(self._nested, self.d))
-        return SimState(round=jnp.int32(0), flat_w=flat,
-                        ef=jnp.zeros((self.k, self.d), jnp.float32),
-                        tcs_prev=flat, rng=jax.random.PRNGKey(seed),
-                        stage_ef=stage_ef)
+        return self._place(SimState(
+            round=jnp.int32(0), flat_w=flat,
+            ef=jnp.zeros((self.k, self.d), jnp.float32), tcs_prev=flat,
+            rng=jax.random.PRNGKey(seed), stage_ef=stage_ef))
+
+    def _state_shardings(self, state: SimState) -> SimState:
+        """Device-backend state layout: EF rows over the client axis (the
+        axis before d), everything else replicated."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        mesh = self._mesh
+        rep = NamedSharding(mesh, P())
+        lead = (None,) * (state.ef.ndim - 2)        # the cohort axis, if any
+        return jax.tree.map(lambda _: rep, state)._replace(
+            ef=NamedSharding(mesh, P(*lead, mesh.axis_names[0])))
+
+    def _place(self, state: SimState) -> SimState:
+        """Commit a host-built state to the device round's state layout.
+
+        The device round constrains its output state to the same layout
+        (:meth:`_pin`), so round 1 and every later round hit one jit
+        specialization instead of compiling again on round 2."""
+        if self._mesh is None:
+            return state
+        return jax.device_put(state, self._state_shardings(state))
+
+    def _pin(self, state: SimState) -> SimState:
+        if self._mesh is None:
+            return state
+        return jax.lax.with_sharding_constraint(
+            state, self._state_shardings(state))
 
     # -- one jitted round ---------------------------------------------------
     def round_fn(self) -> Callable:
@@ -301,9 +327,9 @@ class Simulator:
             d_total = jnp.maximum(jnp.sum(weights * part), 1e-9)
             flat_new = state.flat_w + res.aggregate / d_total
 
-            new_state = SimState(round=state.round + 1, flat_w=flat_new,
-                                 ef=res.e_new, tcs_prev=tcs_prev, rng=rng,
-                                 stage_ef=stage_ef)
+            new_state = self._pin(SimState(
+                round=state.round + 1, flat_w=flat_new, ef=res.e_new,
+                tcs_prev=tcs_prev, rng=rng, stage_ef=stage_ef))
             # telemetry riders — tiny [K] reductions of state the round
             # already holds, always computed so collection on/off cannot
             # change the jitted program
@@ -332,12 +358,12 @@ class Simulator:
             raise ValueError("batched rounds run flat plans; nested "
                              "topologies aggregate per cohort")
         states = [self.init(int(s)) for s in seeds]
-        return SimState(
+        return self._place(SimState(
             round=jnp.int32(0),
             flat_w=jnp.stack([s.flat_w for s in states]),
             ef=jnp.stack([s.ef for s in states]),
             tcs_prev=jnp.stack([s.tcs_prev for s in states]),
-            rng=jnp.stack([s.rng for s in states]))
+            rng=jnp.stack([s.rng for s in states])))
 
     def round_fn_batched(self) -> Callable:
         """Cohort-batched round closure — B tenants through ONE launch.
@@ -409,8 +435,9 @@ class Simulator:
                 jnp.sum(weights * part, axis=1), 1e-9)     # [B]
             flat_new = state.flat_w + res.aggregate / d_total[:, None]
 
-            new_state = SimState(round=state.round + 1, flat_w=flat_new,
-                                 ef=res.e_new, tcs_prev=tcs_prev, rng=rng)
+            new_state = self._pin(SimState(
+                round=state.round + 1, flat_w=flat_new, ef=res.e_new,
+                tcs_prev=tcs_prev, rng=rng))
             xs = fed.x.reshape(-1, pc.input_dim)
             ys = fed.y.reshape(-1)
             log = RoundLog(
@@ -629,8 +656,8 @@ class Simulator:
                 and not state.stage_ef):
             # a schedule of nested plans shares one per-stage unit count
             # (validated by TopologySchedule) → one set of EF tiers
-            state = state._replace(stage_ef=zero_stage_ef(
-                topology_schedule.plan_at(0), self.d))
+            state = self._place(state._replace(stage_ef=zero_stage_ef(
+                topology_schedule.plan_at(0), self.d)))
 
         step = jax.jit(self.round_fn())
         cache = _PlanCache(self.k)

@@ -1,13 +1,19 @@
 """Pallas TPU kernels: batched W-lane node-step stages (one pass per level).
 
 The aggregation executors run up to W tree nodes concurrently per level
-(the padded ``(L, W)`` schedule of :class:`repro.agg.plan.AggPlan`). The
-scalar kernels in :mod:`sparsify_ef` / :mod:`chain_accum` fuse one node's
-stage; these variants fuse a **whole level**: inputs carry a leading lane
-axis ``[W, d]``, per-lane scalars (weight, τ, participate) ride in as
-``[W]`` vectors, and the grid is ``(W, blocks)`` so every lane streams its
-d-vector tile by tile in one ``pallas_call`` — no ``vmap`` over scalar
-kernels, no per-lane dispatch overhead.
+(the padded ``(L, W)`` schedule of :class:`repro.agg.plan.AggPlan`). These
+kernels fuse a **whole level**: inputs carry a leading lane axis
+``[W, d]``, per-lane scalars (weight, τ, participate) ride in as ``[W]``
+vectors held whole in SMEM, and the grid is ``(W, blocks)`` so every lane
+streams its d-vector tile by tile in one ``pallas_call`` — no ``vmap``
+over per-node kernels, no per-lane dispatch overhead. A single node is the
+``W = 1`` case.
+
+Per-lane counts (nnz, off-mask nnz, candidate counts) and ‖e'‖² gather in
+``(SUBLANES, VREG)`` VMEM accumulator tiles that stay resident across the
+block axis; the wrappers reduce them. Every block is either the whole
+array in its last two dimensions or (8, 128)-aligned, which is what the
+TPU compiler accepts.
 
 Padding lanes (``valid == 0`` — the schedule's no-op slots) skip the
 elementwise math entirely (``pl.when``) and write zeros, which keeps the
@@ -49,10 +55,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 SUBLANES = 8
 LANES = 1024
 BLOCK = SUBLANES * LANES
+VREG = 128                  # lanes of one vector register
 
 
 def _pad_lanes(v: jax.Array, n_blocks: int, pad: int):
@@ -94,7 +102,48 @@ def _blk_cohort(lanes_per_cohort: int):
 
 
 def _lane():
-    return pl.BlockSpec((1,), lambda w, j: (w,))
+    # a per-lane scalar table ([W] or [W, n]) lives whole in SMEM; the
+    # kernel reads its own lane's entries with ``ref[w]`` / ``ref[w, i]``
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _acc(*lead: int):
+    # per-lane (SUBLANES, VREG) accumulator tile(s), resident across the
+    # block axis j and reduced in the wrapper — no scalar store per tile
+    zeros = (0,) * (len(lead) + 2)
+    return pl.BlockSpec((1, *lead, SUBLANES, VREG),
+                        lambda w, j: (w, *zeros))
+
+
+def _acc_shape(w_lanes: int, *lead: int, dtype=jnp.int32):
+    return jax.ShapeDtypeStruct((w_lanes, *lead, SUBLANES, VREG), dtype)
+
+
+def _tile(ref):
+    """The current (SUBLANES, LANES) f32 tile of a blocked operand."""
+    return ref[...].reshape(SUBLANES, LANES).astype(jnp.float32)
+
+
+def _store(ref, x):
+    ref[...] = x.reshape(ref.shape).astype(ref.dtype)
+
+
+def _fold(x):
+    """(SUBLANES, LANES) → (SUBLANES, VREG): sum of the aligned column
+    groups; used for integer counts, whose order does not matter."""
+    acc = x[:, :VREG]
+    for k in range(VREG, LANES, VREG):
+        acc = acc + x[:, k:k + VREG]
+    return acc
+
+
+def _count(pred):
+    return _fold(pred.astype(jnp.int32))
+
+
+def _counts(acc):
+    """Per-lane totals of ``_count`` accumulator tiles."""
+    return jnp.sum(acc, axis=(-2, -1), dtype=jnp.int32)
 
 
 def _gmask_operand(gmask, w_lanes: int, gmask_cohorts: int, n_blocks: int,
@@ -131,22 +180,31 @@ def _drop_pad_level(counts, taus, pad: int):
 
 
 def _pinned_tile_err(sq):
-    """Pairwise-fold an (SUBLANES, LANES) tile of squares to a scalar.
+    """Pairwise-fold an (SUBLANES, LANES) tile of squares to its sum.
 
     The documented ``err_sq_mode="kernel"`` summation order: lanes fold
     pairwise 1024 → 512 → … → 1 (``x[:, :n] + x[:, n:2n]``), then sublanes
     8 → 4 → 2 → 1; block scalars accumulate left-to-right in grid order.
+
+    Folds down to one vector register use aligned slices. Below that, a
+    rotation by the half-width ``n`` adds element ``i ± n`` to element
+    ``i``: the row is periodic in ``2n`` after the previous step, so both
+    rotation directions give ``x[i mod 2n] + x[(i + n) mod 2n]`` — the
+    reference's pairs, in every lane. Returns a (SUBLANES, VREG) tile whose
+    every element holds the tile's sum.
     """
-    sq = sq.reshape(SUBLANES, LANES)
     n = LANES
-    while n > 1:
+    while n > VREG:
         n //= 2
         sq = sq[:, :n] + sq[:, n:2 * n]
+    while n > 1:
+        n //= 2
+        sq = sq + pltpu.roll(sq, n, 1)
     m = SUBLANES
     while m > 1:
         m //= 2
-        sq = sq[:m, :] + sq[m:2 * m, :]
-    return sq[0, 0]
+        sq = sq + pltpu.roll(sq, m, 0)
+    return sq
 
 
 # ---------------------------------------------------------------------------
@@ -160,30 +218,27 @@ def _sparsify_ef_level_kernel(g_ref, e_ref, w_ref, tau_ref, v_ref, *rest,
     if with_err:
         *rest, err_ref = rest
     gbar_ref, enew_ref, nnz_ref = rest
-    j = pl.program_id(1)
+    lane, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        nnz_ref[0] = jnp.int32(0)
+        nnz_ref[...] = jnp.zeros_like(nnz_ref)
         if with_err:
-            err_ref[0] = jnp.float32(0)
+            err_ref[...] = jnp.zeros_like(err_ref)
 
-    ok = v_ref[0] > 0
+    ok = v_ref[lane] > 0
 
     @pl.when(ok)
     def _compute():
-        w = w_ref[0]
-        tau = tau_ref[0]
-        gt = (w * g_ref[...].astype(jnp.float32)
-              + e_ref[...].astype(jnp.float32))
-        keep = jnp.abs(gt) >= tau
+        gt = w_ref[lane] * _tile(g_ref) + _tile(e_ref)
+        keep = jnp.abs(gt) >= tau_ref[lane]
         if has_mask:
-            keep = keep | (m_ref[...] > 0)
+            keep = keep | (_tile(m_ref) > 0)
         gbar = jnp.where(keep, gt, 0.0)
         e_new = gt - gbar
-        gbar_ref[...] = gbar.astype(gbar_ref.dtype)
-        enew_ref[...] = e_new.astype(enew_ref.dtype)
-        nnz_ref[0] += jnp.sum(gbar != 0).astype(jnp.int32)
+        _store(gbar_ref, gbar)
+        _store(enew_ref, e_new)
+        nnz_ref[0] += _count(gbar != 0)
         if with_err:
             err_ref[0] += _pinned_tile_err(e_new * e_new)
 
@@ -217,15 +272,15 @@ def sparsify_ef_level_pallas(g, e, mask_in, weight, tau, valid, *,
         operands.append(_pad_lanes(mask_in.astype(jnp.float32), n_blocks,
                                    pad))
         in_specs.append(_blk())
-    out_specs = [_blk(), _blk(), _lane()]
+    out_specs = [_blk(), _blk(), _acc()]
     out_shape = [
         jax.ShapeDtypeStruct(gp.shape, g.dtype),
         jax.ShapeDtypeStruct(ep.shape, e.dtype),
-        jax.ShapeDtypeStruct((w_lanes,), jnp.int32),
+        _acc_shape(w_lanes),
     ]
     if with_err:
-        out_specs.append(_lane())
-        out_shape.append(jax.ShapeDtypeStruct((w_lanes,), jnp.float32))
+        out_specs.append(_acc())
+        out_shape.append(_acc_shape(w_lanes, dtype=jnp.float32))
 
     out = pl.pallas_call(
         functools.partial(_sparsify_ef_level_kernel, has_mask=has_mask,
@@ -238,8 +293,8 @@ def sparsify_ef_level_pallas(g, e, mask_in, weight, tau, valid, *,
     )(*operands)
     gbar, e_new, nnz = out[:3]
     res = (gbar.reshape(w_lanes, -1)[:, :d],
-           e_new.reshape(w_lanes, -1)[:, :d], nnz)
-    return res + (out[3],) if with_err else res
+           e_new.reshape(w_lanes, -1)[:, :d], _counts(nnz))
+    return res + (out[3][:, 0, 0],) if with_err else res
 
 
 # ---------------------------------------------------------------------------
@@ -252,26 +307,25 @@ def _chain_accum_level_kernel(gin_ref, gbar_ref, v_ref, *rest,
         gm_ref, gout_ref, nnz_ref, off_ref = rest
     else:
         gout_ref, nnz_ref, off_ref = rest
-    j = pl.program_id(1)
+    lane, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        nnz_ref[0] = jnp.int32(0)
-        off_ref[0] = jnp.int32(0)
+        nnz_ref[...] = jnp.zeros_like(nnz_ref)
+        off_ref[...] = jnp.zeros_like(off_ref)
 
-    ok = v_ref[0] > 0
+    ok = v_ref[lane] > 0
 
     @pl.when(ok)
     def _compute():
-        gamma = (gin_ref[...].astype(jnp.float32)
-                 + gbar_ref[...].astype(jnp.float32))
-        gout_ref[...] = gamma.astype(gout_ref.dtype)
+        gamma = _tile(gin_ref) + _tile(gbar_ref)
+        _store(gout_ref, gamma)
         nz = gamma != 0
-        nnz_ref[0] += jnp.sum(nz).astype(jnp.int32)
+        nnz_ref[0] += _count(nz)
         if has_gmask:
-            off_ref[0] += jnp.sum(nz & (gm_ref[...] <= 0)).astype(jnp.int32)
+            off_ref[0] += _count(nz & (_tile(gm_ref) <= 0))
         else:
-            off_ref[0] += jnp.sum(nz).astype(jnp.int32)
+            off_ref[0] += _count(nz)
 
     @pl.when(jnp.logical_not(ok))
     def _skip():
@@ -309,15 +363,15 @@ def chain_accum_level_pallas(gamma_in, gbar, valid, gmask=None, *,
         functools.partial(_chain_accum_level_kernel, has_gmask=has_gmask),
         grid=(w_lanes, n_blocks),
         in_specs=in_specs,
-        out_specs=[_blk(), _lane(), _lane()],
+        out_specs=[_blk(), _acc(), _acc()],
         out_shape=[
             jax.ShapeDtypeStruct(gi.shape, gamma_in.dtype),
-            jax.ShapeDtypeStruct((w_lanes,), jnp.int32),
-            jax.ShapeDtypeStruct((w_lanes,), jnp.int32),
+            _acc_shape(w_lanes),
+            _acc_shape(w_lanes),
         ],
         interpret=interpret,
     )(*operands)
-    return gout.reshape(w_lanes, -1)[:, :d], nnz, nnz_off
+    return gout.reshape(w_lanes, -1)[:, :d], _counts(nnz), _counts(nnz_off)
 
 
 # ---------------------------------------------------------------------------
@@ -339,48 +393,45 @@ def _cl_fuse_level_kernel(g_ref, e_ref, gin_ref, w_ref, tau_ref, p_ref,
         gout_ref, enew_ref, nnz_ref, off_ref, err_ref = rest[idx:]
     else:
         gout_ref, enew_ref, nnz_ref, off_ref = rest[idx:]
-    j = pl.program_id(1)
+    lane, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        nnz_ref[0] = jnp.int32(0)
-        off_ref[0] = jnp.int32(0)
+        nnz_ref[...] = jnp.zeros_like(nnz_ref)
+        off_ref[...] = jnp.zeros_like(off_ref)
         if with_err:
-            err_ref[0] = jnp.float32(0)
+            err_ref[...] = jnp.zeros_like(err_ref)
 
-    ok = v_ref[0] > 0
+    ok = v_ref[lane] > 0
 
     @pl.when(ok)
     def _compute():
-        w = w_ref[0]
-        tau = tau_ref[0]
-        p = p_ref[0]
-        gt = (w * g_ref[...].astype(jnp.float32)
-              + e_ref[...].astype(jnp.float32))
-        gin = gin_ref[...].astype(jnp.float32)
+        p = p_ref[lane]
+        gt = w_ref[lane] * _tile(g_ref) + _tile(e_ref)
+        gin = _tile(gin_ref)
         s = p * gt + gin
         if has_gmask:
-            m = gm_ref[...]
+            m = _tile(gm_ref)
             lam_t = (1.0 - m) * s
         else:
             lam_t = s
-        keep = jnp.abs(lam_t) >= tau
+        keep = jnp.abs(lam_t) >= tau_ref[lane]
         if has_mask:
-            keep = keep | (mask_ref[...] > 0)
+            keep = keep | (_tile(mask_ref) > 0)
         lam = jnp.where(keep, lam_t, 0.0)
         e_new = lam_t - lam
         gamma = (m * s + lam) if has_gmask else lam
         alive = p > 0
         gamma = jnp.where(alive, gamma, gin)
         e_new = jnp.where(alive, e_new, gt)
-        gout_ref[...] = gamma.astype(gout_ref.dtype)
-        enew_ref[...] = e_new.astype(enew_ref.dtype)
+        _store(gout_ref, gamma)
+        _store(enew_ref, e_new)
         nz = gamma != 0
-        nnz_ref[0] += jnp.sum(nz).astype(jnp.int32)
+        nnz_ref[0] += _count(nz)
         if has_gmask:
-            off_ref[0] += jnp.sum(nz & (gm_ref[...] <= 0)).astype(jnp.int32)
+            off_ref[0] += _count(nz & (m <= 0))
         else:
-            off_ref[0] += jnp.sum(nz).astype(jnp.int32)
+            off_ref[0] += _count(nz)
         if with_err:
             err_ref[0] += _pinned_tile_err(e_new * e_new)
 
@@ -430,16 +481,16 @@ def cl_fuse_level_pallas(g, e, gamma_in, weight, tau, participate, valid,
         operands.append(_pad_lanes(mask_in.astype(jnp.float32), n_blocks,
                                    pad))
         in_specs.append(_blk())
-    out_specs = [_blk(), _blk(), _lane(), _lane()]
+    out_specs = [_blk(), _blk(), _acc(), _acc()]
     out_shape = [
         jax.ShapeDtypeStruct(gi.shape, gamma_in.dtype),
         jax.ShapeDtypeStruct(ep.shape, e.dtype),
-        jax.ShapeDtypeStruct((w_lanes,), jnp.int32),
-        jax.ShapeDtypeStruct((w_lanes,), jnp.int32),
+        _acc_shape(w_lanes),
+        _acc_shape(w_lanes),
     ]
     if with_err:
-        out_specs.append(_lane())
-        out_shape.append(jax.ShapeDtypeStruct((w_lanes,), jnp.float32))
+        out_specs.append(_acc())
+        out_shape.append(_acc_shape(w_lanes, dtype=jnp.float32))
 
     out = pl.pallas_call(
         functools.partial(_cl_fuse_level_kernel, has_gmask=has_gmask,
@@ -452,29 +503,32 @@ def cl_fuse_level_pallas(g, e, gamma_in, weight, tau, participate, valid,
     )(*operands)
     gout, e_new, nnz, nnz_off = out[:4]
     res = (gout.reshape(w_lanes, -1)[:, :d],
-           e_new.reshape(w_lanes, -1)[:, :d], nnz, nnz_off)
-    return res + (out[4],) if with_err else res
+           e_new.reshape(w_lanes, -1)[:, :d], _counts(nnz),
+           _counts(nnz_off))
+    return res + (out[4][:, 0, 0],) if with_err else res
 
 
 # ---------------------------------------------------------------------------
 # count_ge_level — per-lane candidate-threshold counting (batched bisection)
 # ---------------------------------------------------------------------------
 
-def _count_ge_level_kernel(x_ref, taus_ref, out_ref, *, branch: int):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    mag = jnp.abs(x_ref[...].astype(jnp.float32))
+def _count_candidates(out_ref, mag, taus_ref, lane, branch: int):
+    """out_ref[0, b] += #{mag >= taus[lane, b]} (folded per tile)."""
 
     def body(b, _):
-        tau = taus_ref[0, b]
-        out_ref[0, b] += jnp.sum(mag >= tau).astype(jnp.int32)
+        out_ref[0, b] += _count(mag >= taus_ref[lane, b])
         return ()
 
     jax.lax.fori_loop(0, branch, body, ())
+
+
+def _count_ge_level_kernel(x_ref, taus_ref, out_ref, *, branch: int):
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    _count_candidates(out_ref, jnp.abs(_tile(x_ref)), taus_ref,
+                      pl.program_id(0), branch)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -496,20 +550,19 @@ def count_ge_level_pallas(x: jax.Array, taus: jax.Array, *,
     out = pl.pallas_call(
         functools.partial(_count_ge_level_kernel, branch=branch),
         grid=(w_lanes, n_blocks),
-        in_specs=[_blk(),
-                  pl.BlockSpec((1, branch), lambda w, j: (w, 0))],
-        out_specs=pl.BlockSpec((1, branch), lambda w, j: (w, 0)),
-        out_shape=jax.ShapeDtypeStruct((w_lanes, branch), jnp.int32),
+        in_specs=[_blk(), _lane()],
+        out_specs=_acc(branch),
+        out_shape=_acc_shape(w_lanes, branch),
         interpret=interpret,
     )(xp, taus)
-    return _drop_pad_level(out, taus, pad)
+    return _drop_pad_level(_counts(out), taus, pad)
 
 
 # ---------------------------------------------------------------------------
 # count_ge_fused_level — operand-on-the-fly candidate counting per lane
 # ---------------------------------------------------------------------------
 
-def _fused_operand_tile(g_ref, e_ref, gin_ref, gm_ref, w_ref, p_ref, *,
+def _fused_operand_tile(g_ref, e_ref, gin_ref, gm_ref, w_ref, p_ref, lane, *,
                         include_gamma: bool, has_gmask: bool):
     """Reconstruct one (8, LANES) tile of the bisection operand in VMEM.
 
@@ -517,17 +570,16 @@ def _fused_operand_tile(g_ref, e_ref, gin_ref, gm_ref, w_ref, p_ref, *,
     (and the materialized jnp path): ``(1−m)·(p·(w·g + e) + γ_in)`` with
     the γ/mask factors dropped per the static flags.
     """
-    op = (w_ref[0] * g_ref[...].astype(jnp.float32)
-          + e_ref[...].astype(jnp.float32))
+    op = w_ref[lane] * _tile(g_ref) + _tile(e_ref)
     if include_gamma:
-        op = p_ref[0] * op + gin_ref[...].astype(jnp.float32)
+        op = p_ref[lane] * op + _tile(gin_ref)
     if has_gmask:
-        op = (1.0 - gm_ref[...]) * op
+        op = (1.0 - _tile(gm_ref)) * op
     return op
 
 
-def _count_ge_fused_level_kernel(g_ref, e_ref, *rest, branch: int,
-                                 include_gamma: bool, has_gmask: bool):
+def _fused_refs(rest, include_gamma: bool, has_gmask: bool):
+    """Split ``(γ_in?, w, p, m?, *outs)`` of the fused-operand kernels."""
     idx = 0
     gin_ref = gm_ref = None
     if include_gamma:
@@ -538,22 +590,46 @@ def _count_ge_fused_level_kernel(g_ref, e_ref, *rest, branch: int,
     if has_gmask:
         gm_ref = rest[idx]
         idx += 1
-    taus_ref, out_ref = rest[idx:]
-    j = pl.program_id(1)
+    return gin_ref, w_ref, p_ref, gm_ref, rest[idx:]
 
-    @pl.when(j == 0)
+
+def _fused_operands(g, e, gamma_in, weight, participate, gmask, *,
+                    include_gamma: bool, gmask_cohorts: int):
+    """Operands and block specs shared by the fused-operand kernels."""
+    w_lanes, d = g.shape
+    n_blocks, pad = _geometry(d)
+    operands = [_pad_lanes(g.astype(jnp.float32), n_blocks, pad),
+                _pad_lanes(e.astype(jnp.float32), n_blocks, pad)]
+    in_specs = [_blk(), _blk()]
+    if include_gamma:
+        operands.append(_pad_lanes(gamma_in.astype(jnp.float32), n_blocks,
+                                   pad))
+        in_specs.append(_blk())
+    operands += [weight.astype(jnp.float32),
+                 participate.astype(jnp.float32)]
+    in_specs += [_lane(), _lane()]
+    if gmask is not None:
+        op, spec = _gmask_operand(gmask, w_lanes, gmask_cohorts, n_blocks,
+                                  pad)
+        operands.append(op)
+        in_specs.append(spec)
+    return operands, in_specs, (w_lanes, n_blocks), pad
+
+
+def _count_ge_fused_level_kernel(g_ref, e_ref, *rest, branch: int,
+                                 include_gamma: bool, has_gmask: bool):
+    gin_ref, w_ref, p_ref, gm_ref, (taus_ref, out_ref) = _fused_refs(
+        rest, include_gamma, has_gmask)
+    lane = pl.program_id(0)
+
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
     mag = jnp.abs(_fused_operand_tile(
-        g_ref, e_ref, gin_ref, gm_ref, w_ref, p_ref,
+        g_ref, e_ref, gin_ref, gm_ref, w_ref, p_ref, lane,
         include_gamma=include_gamma, has_gmask=has_gmask))
-
-    def body(b, _):
-        out_ref[0, b] += jnp.sum(mag >= taus_ref[0, b]).astype(jnp.int32)
-        return ()
-
-    jax.lax.fori_loop(0, branch, body, ())
+    _count_candidates(out_ref, mag, taus_ref, lane, branch)
 
 
 @functools.partial(jax.jit, static_argnames=("include_gamma",
@@ -571,39 +647,23 @@ def count_ge_fused_level_pallas(g, e, gamma_in, weight, participate, taus,
     :func:`_gmask_operand`. Returns counts [W, B] i32; zero padding
     reconstructs to exactly 0.0 and is subtracted in the wrapper.
     """
-    w_lanes, d = g.shape
     branch = taus.shape[-1]
-    n_blocks, pad = _geometry(d)
-    has_gmask = gmask is not None
     taus = taus.astype(jnp.float32)
-    operands = [_pad_lanes(g.astype(jnp.float32), n_blocks, pad),
-                _pad_lanes(e.astype(jnp.float32), n_blocks, pad)]
-    in_specs = [_blk(), _blk()]
-    if include_gamma:
-        operands.append(_pad_lanes(gamma_in.astype(jnp.float32), n_blocks,
-                                   pad))
-        in_specs.append(_blk())
-    operands += [weight.astype(jnp.float32),
-                 participate.astype(jnp.float32)]
-    in_specs += [_lane(), _lane()]
-    if has_gmask:
-        op, spec = _gmask_operand(gmask, w_lanes, gmask_cohorts, n_blocks,
-                                  pad)
-        operands.append(op)
-        in_specs.append(spec)
-    operands.append(taus)
-    in_specs.append(pl.BlockSpec((1, branch), lambda w, j: (w, 0)))
+    operands, in_specs, grid, pad = _fused_operands(
+        g, e, gamma_in, weight, participate, gmask,
+        include_gamma=include_gamma, gmask_cohorts=gmask_cohorts)
 
     out = pl.pallas_call(
         functools.partial(_count_ge_fused_level_kernel, branch=branch,
-                          include_gamma=include_gamma, has_gmask=has_gmask),
-        grid=(w_lanes, n_blocks),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, branch), lambda w, j: (w, 0)),
-        out_shape=jax.ShapeDtypeStruct((w_lanes, branch), jnp.int32),
+                          include_gamma=include_gamma,
+                          has_gmask=gmask is not None),
+        grid=grid,
+        in_specs=in_specs + [_lane()],
+        out_specs=_acc(branch),
+        out_shape=_acc_shape(grid[0], branch),
         interpret=interpret,
-    )(*operands)
-    return _drop_pad_level(out, taus, pad)
+    )(*operands, taus)
+    return _drop_pad_level(_counts(out), taus, pad)
 
 
 # ---------------------------------------------------------------------------
@@ -612,32 +672,23 @@ def count_ge_fused_level_pallas(g, e, gamma_in, weight, participate, taus,
 
 def _hist_topq_level_kernel(g_ref, e_ref, *rest, branch: int,
                             include_gamma: bool, has_gmask: bool):
-    idx = 0
-    gin_ref = gm_ref = None
-    if include_gamma:
-        gin_ref = rest[idx]
-        idx += 1
-    w_ref, p_ref = rest[idx:idx + 2]
-    idx += 2
-    if has_gmask:
-        gm_ref = rest[idx]
-        idx += 1
-    tau1_ref, nl_ref, w2_ref, ts_ref, d2_ref, f_ref = rest[idx:]
-    j = pl.program_id(1)
+    gin_ref, w_ref, p_ref, gm_ref, outs = _fused_refs(
+        rest, include_gamma, has_gmask)
+    tau1_ref, nl_ref, w2_ref, ts_ref, hist_ref = outs
+    lane = pl.program_id(0)
 
-    @pl.when(j == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
-        d2_ref[...] = jnp.zeros_like(d2_ref)
-        f_ref[...] = jnp.zeros_like(f_ref)
+        hist_ref[...] = jnp.zeros_like(hist_ref)
 
     mag = jnp.abs(_fused_operand_tile(
-        g_ref, e_ref, gin_ref, gm_ref, w_ref, p_ref,
+        g_ref, e_ref, gin_ref, gm_ref, w_ref, p_ref, lane,
         include_gamma=include_gamma, has_gmask=has_gmask))
 
     # round-1 digit: #{candidates <= |x|} — one vectorized compare per
     # candidate (the whole search's streaming passes collapse to this one)
     def cnt1(b, acc):
-        return acc + (mag >= tau1_ref[0, b]).astype(jnp.int32)
+        return acc + (mag >= tau1_ref[lane, b]).astype(jnp.int32)
 
     d1 = jax.lax.fori_loop(0, branch, cnt1, jnp.zeros_like(mag, jnp.int32))
 
@@ -646,9 +697,9 @@ def _hist_topq_level_kernel(g_ref, e_ref, *rest, branch: int,
     def gather(bb, carry):
         nl, w2e, te = carry
         sel = d1 == bb
-        nl = nl + jnp.where(sel, nl_ref[0, bb], 0.0)
-        w2e = w2e + jnp.where(sel, w2_ref[0, bb], 0.0)
-        te = te + jnp.where(sel, ts_ref[0, bb], 0.0)
+        nl = nl + jnp.where(sel, nl_ref[lane, bb], 0.0)
+        w2e = w2e + jnp.where(sel, w2_ref[lane, bb], 0.0)
+        te = te + jnp.where(sel, ts_ref[lane, bb], 0.0)
         return nl, w2e, te
 
     zeros = jnp.zeros_like(mag)
@@ -662,21 +713,21 @@ def _hist_topq_level_kernel(g_ref, e_ref, *rest, branch: int,
         return acc + (mag >= cand).astype(jnp.int32)
 
     d2 = jax.lax.fori_loop(0, branch, cnt2, jnp.zeros_like(mag, jnp.int32))
-    flag = (mag >= te).astype(jnp.float32)
+    flag = mag >= te
 
-    # joint histogram via one-hot contraction: D2[r, c] = Σ 1[d1=r]·1[d2=c]
-    # — one dot_general on the MXU, exact in f32 (counts < 2²⁴)
-    iota = jax.lax.broadcasted_iota(jnp.int32,
-                                    (SUBLANES, LANES, branch + 1), 2)
-    oh1 = (d1[0, 0][..., None] == iota).astype(jnp.float32)
-    oh2 = (d2[0, 0][..., None] == iota).astype(jnp.float32)
-    dnums = (((0, 1), (0, 1)), ((), ()))
-    d2_ref[0] += jax.lax.dot_general(
-        oh1, oh2, dnums, preferred_element_type=jnp.float32
-    ).astype(jnp.int32)
-    f_ref[0] += jax.lax.dot_general(
-        oh1, flag[0, 0], dnums, preferred_element_type=jnp.float32
-    ).astype(jnp.int32)
+    # joint histogram via one-hot contraction on the MXU, one tile row at a
+    # time: H[r, c] = Σ_i 1[d1_i = r]·1[d2_i = c], with the last column
+    # counting the flag per d1 bin. 0/1 operands and ≤ LANES-term sums are
+    # exact in bf16 × bf16 → f32.
+    bins = hist_ref.shape[-1]
+    iota = jax.lax.broadcasted_iota(jnp.int32, (bins, LANES), 0)
+    for s in range(SUBLANES):
+        oh1 = (iota == d1[s:s + 1]).astype(jnp.bfloat16)
+        oh2 = ((iota == d2[s:s + 1])
+               | ((iota == bins - 1) & flag[s:s + 1])).astype(jnp.bfloat16)
+        hist_ref[0] += jax.lax.dot_general(
+            oh1, oh2, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("include_gamma",
@@ -702,45 +753,21 @@ def hist_topq_level_pallas(g, e, gamma_in, weight, participate, tables,
     reconstructs to operand 0.0 → bin D2[·, 0, 0], which the
     reconstruction never reads (all candidates are strictly positive).
     """
-    w_lanes, d = g.shape
-    tau1, new_lo, w2, top_shift = tables
+    tau1 = tables[0]
     branch = tau1.shape[-1]
-    n_blocks, pad = _geometry(d)
-    has_gmask = gmask is not None
-    operands = [_pad_lanes(g.astype(jnp.float32), n_blocks, pad),
-                _pad_lanes(e.astype(jnp.float32), n_blocks, pad)]
-    in_specs = [_blk(), _blk()]
-    if include_gamma:
-        operands.append(_pad_lanes(gamma_in.astype(jnp.float32), n_blocks,
-                                   pad))
-        in_specs.append(_blk())
-    operands += [weight.astype(jnp.float32),
-                 participate.astype(jnp.float32)]
-    in_specs += [_lane(), _lane()]
-    if has_gmask:
-        op, spec = _gmask_operand(gmask, w_lanes, gmask_cohorts, n_blocks,
-                                  pad)
-        operands.append(op)
-        in_specs.append(spec)
-    row = lambda n: pl.BlockSpec((1, n), lambda w, j: (w, 0))
-    operands += [tau1.astype(jnp.float32), new_lo.astype(jnp.float32),
-                 w2.astype(jnp.float32), top_shift.astype(jnp.float32)]
-    in_specs += [row(branch), row(branch + 1), row(branch + 1),
-                 row(branch + 1)]
+    bins = -(-(branch + 2) // VREG) * VREG      # digits 0..b + the flag
+    operands, in_specs, grid, _ = _fused_operands(
+        g, e, gamma_in, weight, participate, gmask,
+        include_gamma=include_gamma, gmask_cohorts=gmask_cohorts)
 
-    D2, F = pl.pallas_call(
+    hist = pl.pallas_call(
         functools.partial(_hist_topq_level_kernel, branch=branch,
-                          include_gamma=include_gamma, has_gmask=has_gmask),
-        grid=(w_lanes, n_blocks),
-        in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, branch + 1, branch + 1),
-                                lambda w, j: (w, 0, 0)),
-                   row(branch + 1)],
-        out_shape=[
-            jax.ShapeDtypeStruct((w_lanes, branch + 1, branch + 1),
-                                 jnp.int32),
-            jax.ShapeDtypeStruct((w_lanes, branch + 1), jnp.int32),
-        ],
+                          include_gamma=include_gamma,
+                          has_gmask=gmask is not None),
+        grid=grid,
+        in_specs=in_specs + [_lane()] * 4,
+        out_specs=pl.BlockSpec((1, bins, bins), lambda w, j: (w, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((grid[0], bins, bins), jnp.int32),
         interpret=interpret,
-    )(*operands)
-    return D2, F
+    )(*operands, *(t.astype(jnp.float32) for t in tables))
+    return hist[:, :branch + 1, :branch + 1], hist[:, :branch + 1, bins - 1]

@@ -18,16 +18,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.chain_accum import chain_accum_pallas, cl_fuse_pallas
 from repro.kernels.level import (chain_accum_level_pallas,
                                  cl_fuse_level_pallas,
                                  count_ge_fused_level_pallas,
                                  count_ge_level_pallas,
                                  hist_topq_level_pallas,
                                  sparsify_ef_level_pallas)
-from repro.kernels.sparsify_ef import sparsify_ef_pallas
-from repro.kernels.topq_threshold import (count_ge_fused_pallas,
-                                          count_ge_pallas)
 
 Mode = Literal["auto", "always", "never", "ref"]
 
@@ -59,41 +55,6 @@ def resolve(mode: Mode) -> tuple[bool, bool]:
     return False, False
 
 
-_resolve = resolve          # historic private alias
-
-
-def count_ge(x: jax.Array, taus: jax.Array, *, mode: Mode = "auto"):
-    use, interp = _resolve(mode)
-    if use:
-        return count_ge_pallas(x, taus, interpret=interp)
-    return ref.ref_count_ge(x, taus)
-
-
-def sparsify_ef(g, e, mask_in, weight, tau, *, mode: Mode = "auto"):
-    use, interp = _resolve(mode)
-    if use:
-        return sparsify_ef_pallas(g, e, mask_in, jnp.asarray(weight),
-                                  jnp.asarray(tau), interpret=interp)
-    return ref.ref_sparsify_ef(g, e, mask_in, jnp.asarray(weight),
-                               jnp.asarray(tau))
-
-
-def chain_accum(gamma_in, gbar, *, mode: Mode = "auto"):
-    use, interp = _resolve(mode)
-    if use:
-        return chain_accum_pallas(gamma_in, gbar, interpret=interp)
-    return ref.ref_chain_accum(gamma_in, gbar)
-
-
-def cl_fuse(g, e, gamma_in, weight, tau, *, mode: Mode = "auto"):
-    use, interp = _resolve(mode)
-    if use:
-        return cl_fuse_pallas(g, e, gamma_in, jnp.asarray(weight),
-                              jnp.asarray(tau), interpret=interp)
-    return ref.ref_cl_fuse(g, e, gamma_in, jnp.asarray(weight),
-                           jnp.asarray(tau))
-
-
 # ---------------------------------------------------------------------------
 # Batched W-lane level variants (the (L, W) schedule hot path)
 # ---------------------------------------------------------------------------
@@ -106,7 +67,7 @@ def sparsify_ef_level(g, e, mask_in, weight, tau, valid, *,
     the ``err_sq_mode="kernel"`` path; both backends use the identical
     pairwise-tree fold.
     """
-    use, interp = _resolve(mode)
+    use, interp = resolve(mode)
     if use:
         return sparsify_ef_level_pallas(g, e, mask_in, jnp.asarray(weight),
                                         jnp.asarray(tau),
@@ -125,7 +86,7 @@ def chain_accum_level(gamma_in, gbar, valid, gmask=None, *,
     ``gmask_cohorts=B`` marks a cohort-shared [B, d] gmask for lanes laid
     out cohort-major (the multi-tenant batched round path).
     """
-    use, interp = _resolve(mode)
+    use, interp = resolve(mode)
     if use:
         return chain_accum_level_pallas(gamma_in, gbar, jnp.asarray(valid),
                                         gmask, gmask_cohorts=gmask_cohorts,
@@ -141,7 +102,7 @@ def cl_fuse_level(g, e, gamma_in, weight, tau, participate, valid,
 
     ``with_err=True`` appends the in-kernel pinned-order ‖e'‖² ([W] f32).
     """
-    use, interp = _resolve(mode)
+    use, interp = resolve(mode)
     if use:
         return cl_fuse_level_pallas(g, e, gamma_in, jnp.asarray(weight),
                                     jnp.asarray(tau),
@@ -158,7 +119,7 @@ def cl_fuse_level(g, e, gamma_in, weight, tau, participate, valid,
 
 def count_ge_level(x: jax.Array, taus: jax.Array, *, mode: Mode = "auto"):
     """Per-lane candidate-threshold counts ([W, d] × [W, B] → [W, B])."""
-    use, interp = _resolve(mode)
+    use, interp = resolve(mode)
     if use:
         return count_ge_level_pallas(x, taus, interpret=interp)
     return ref.ref_count_ge_level(x, taus)
@@ -168,25 +129,6 @@ def count_ge_level(x: jax.Array, taus: jax.Array, *, mode: Mode = "auto"):
 # Fused-operand τ search (no materialized bisection operand)
 # ---------------------------------------------------------------------------
 
-def count_ge_fused(g, e, gamma_in, weight, participate, taus, *,
-                   include_gamma: bool = False, mode: Mode = "auto"):
-    """Candidate counts of the 1-D bisection operand rebuilt on the fly.
-
-    Operand ``w·g + e`` (``p·(w·g + e) + γ_in`` when ``include_gamma``)
-    is reconstructed tile-by-tile from the raw node inputs — no HBM
-    materialization before the τ search. taus [B] nondecreasing → [B] i32.
-    """
-    use, interp = _resolve(mode)
-    if use:
-        return count_ge_fused_pallas(g, e, gamma_in, jnp.asarray(weight),
-                                     jnp.asarray(participate), taus,
-                                     include_gamma=include_gamma,
-                                     interpret=interp)
-    return ref.ref_count_ge_fused(g, e, gamma_in, jnp.asarray(weight),
-                                  jnp.asarray(participate), taus,
-                                  include_gamma=include_gamma)
-
-
 def count_ge_fused_level(g, e, gamma_in, weight, participate, taus,
                          gmask=None, *, include_gamma: bool = False,
                          gmask_cohorts: int = 0, mode: Mode = "auto"):
@@ -195,7 +137,7 @@ def count_ge_fused_level(g, e, gamma_in, weight, participate, taus,
     Full operand family ``(1−m)·(p·(w·g + e) + γ_in)`` with the γ/mask
     factors dropped per flags; [W, d] inputs, taus [W, B] → [W, B] i32.
     """
-    use, interp = _resolve(mode)
+    use, interp = resolve(mode)
     if use:
         return count_ge_fused_level_pallas(
             g, e, gamma_in, jnp.asarray(weight), jnp.asarray(participate),
@@ -215,7 +157,7 @@ def hist_topq_level(g, e, gamma_in, weight, participate, tables, gmask=None,
     ``tables`` per ``repro.core.sparsify._hist_tables``; returns
     ``(D2 [W, b+1, b+1] i32, F [W, b+1] i32)``.
     """
-    use, interp = _resolve(mode)
+    use, interp = resolve(mode)
     if use:
         return hist_topq_level_pallas(
             g, e, gamma_in, jnp.asarray(weight), jnp.asarray(participate),

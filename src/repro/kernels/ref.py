@@ -236,28 +236,15 @@ def fused_operand(g, e, gamma_in, weight, participate, gmask=None, *,
     return s
 
 
-def ref_count_ge_fused(g, e, gamma_in, weight, participate, taus, *,
-                       include_gamma: bool = False) -> Array:
-    """Scalar fused-operand counts: 1-D node inputs, taus [B] → i32 [B].
-
-    ``taus`` must be nondecreasing (the bisection brackets always are) —
-    the reference counts via :func:`repro.core.sparsify.count_ge_sorted`,
-    whose integers are bit-identical to the O(d·B) broadcast.
-    """
-    op = fused_operand(g[None], e[None],
-                       None if gamma_in is None else gamma_in[None],
-                       jnp.asarray(weight, jnp.float32).reshape(1),
-                       jnp.asarray(participate, jnp.float32).reshape(1),
-                       include_gamma=include_gamma)
-    return sp.count_ge_sorted(jnp.abs(op[0]), taus)
-
-
 def ref_count_ge_fused_level(g, e, gamma_in, weight, participate, taus,
                              gmask=None, *, include_gamma: bool = False,
                              gmask_cohorts: int = 0) -> Array:
     """Batched fused-operand counts ([W,d] inputs, taus [W,B] → i32 [W,B]).
 
-    Per-lane ``taus`` must be nondecreasing (see :func:`ref_count_ge_fused`).
+    Per-lane ``taus`` must be nondecreasing (the bisection brackets always
+    are): the reference counts via
+    :func:`repro.core.sparsify.count_ge_sorted_batch`, whose integers are
+    bit-identical to the O(d·B) broadcast.
     """
     op = fused_operand(g, e, gamma_in, weight, participate, gmask,
                        include_gamma=include_gamma,

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "..",
                                 "benchmarks"))
@@ -82,7 +81,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
            "agg": agg_kind, "status": "ok"}
     t0 = time.time()
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             train_step = build_train_step(cfg, tc, mesh)
             state_sds = jax.eval_shape(

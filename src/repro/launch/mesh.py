@@ -1,6 +1,6 @@
 """Production mesh construction (function, not constant — importing this
-module never touches jax device state). Mesh/axis-type API drift is bridged
-by :mod:`repro.compat`, so these run on 0.4.x and 0.6+ runtimes alike.
+module never touches jax device state). Every axis is ``Auto``
+(:func:`repro.compat.make_mesh`).
 
 A mesh also fixes the *aggregation client set*: the combined DP axes
 (``pod`` × ``data``) are the K clients of the multi-hop round.

@@ -12,7 +12,6 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs import ARCHS, get_config
 from repro.launch.mesh import make_mesh
 from repro.models import model as model_mod
@@ -32,7 +31,7 @@ def main() -> None:
     mesh = make_mesh((len(jax.devices()), 1), ("data", "model"))
     max_len = args.prompt_len + args.gen
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model_mod.init_params(cfg, jax.random.PRNGKey(args.seed))
         prompts = jax.random.randint(jax.random.PRNGKey(1),
                                      (args.batch, args.prompt_len), 0,

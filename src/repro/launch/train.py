@@ -18,7 +18,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import checkpoint as ckpt
-from repro import compat
 from repro.configs import ARCHS, get_config
 from repro.core.algorithms import AggConfig, AggKind
 from repro.data.synthetic import lm_batch, make_bigram_lm
@@ -63,7 +62,9 @@ def _topology(name: str, k: int):
     raise ValueError(f"unknown topology {name!r}")
 
 
-def main() -> None:
+def main(argv=None) -> dict:
+    """Run the CLI with ``argv`` (``sys.argv[1:]`` when None); returns the
+    last step's metrics."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCHS, default="mamba2-130m")
     ap.add_argument("--smoke", action="store_true",
@@ -89,7 +90,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--straggle-p", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     n_dev = len(jax.devices())
     if args.mesh:
@@ -109,7 +110,7 @@ def main() -> None:
 
     agg_plan = make_agg_plan(mesh, _topology(args.topology, dp_size(mesh)))
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = init_state(cfg, tc, mesh, jax.random.PRNGKey(args.seed),
                            topology=agg_plan)
         shardings = state_shardings(cfg, tc, mesh, topology=agg_plan)
@@ -128,6 +129,7 @@ def main() -> None:
         k_dp = dp_size(mesh)
         key = jax.random.PRNGKey(args.seed + 1)
         t0 = time.time()
+        metrics = {}
         for i in range(args.steps):
             key, kb, ks = jax.random.split(key, 3)
             batch = lm_batch(lm, kb, args.batch, args.seq)
@@ -150,6 +152,7 @@ def main() -> None:
         if args.ckpt_dir:
             ckpt.save(args.ckpt_dir, int(state.step), state)
             print(f"checkpointed step {int(state.step)} → {args.ckpt_dir}")
+    return metrics
 
 
 if __name__ == "__main__":

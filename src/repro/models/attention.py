@@ -36,22 +36,11 @@ def _mesh_auto() -> dict:
     serve/prefill paths) must be pinned explicitly or GSPMD will
     un-shard the batch inside attention loops (measured: 36 TB/step of
     batch all-gathers on granite prefill_32k; EXPERIMENTS §Perf it.8)."""
-    from repro import compat
-    mesh = compat.abstract_mesh()
-    names = getattr(mesh, "axis_names", ()) if mesh is not None else ()
-    if not names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return {}
-    try:
-        types = dict(zip(names, mesh.axis_types))
-    except Exception:
-        types = {n: "Auto" for n in names}
-    # 0.4.x meshes carry no axis types → treat every axis as Auto. Inside a
-    # partial-auto shard_map this names manual axes in constraints, which
-    # 0.4.x lowers as valid manual subgroups; suppressing those constraints
-    # instead crashes XLA (`Check failed: sharding.IsManualSubgroup()`,
-    # reproduced on the distributed train step), so the all-Auto fallback
-    # is load-bearing, not an approximation to tighten.
-    return {n: mesh.shape[n] for n in names if "Auto" in str(types[n])}
+    return {n: mesh.shape[n] for n, t in zip(mesh.axis_names, mesh.axis_types)
+            if t == jax.sharding.AxisType.Auto}
 
 
 def _head_axes(hkv: int, g: int):

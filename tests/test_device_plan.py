@@ -305,7 +305,7 @@ tc = TrainConfig(agg=AggConfig(kind=AggKind.CL_SIA, q=1),
 for name, topo in [("star", star_tree(4)),
                    ("grid", tg.grid_graph(2, 2))]:
     plan = make_agg_plan(mesh, topo)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         st = jax.device_put(init_state(cfg, tc, mesh, jax.random.PRNGKey(0)),
                             state_shardings(cfg, tc, mesh))
         step = jax.jit(build_train_step(cfg, tc, mesh, topology=plan))
@@ -320,7 +320,7 @@ for name, topo in [("star", star_tree(4)),
 
 # the default (topology=None) is still the rotated ring — identical
 # metrics to the explicit ring chain plan
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     st0 = jax.device_put(init_state(cfg, tc, mesh, jax.random.PRNGKey(0)),
                          state_shardings(cfg, tc, mesh))
     s_ring, m_ring = jax.jit(build_train_step(cfg, tc, mesh))(st0, dict(batch))

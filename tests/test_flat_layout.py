@@ -29,7 +29,7 @@ f = compat.shard_map(roundtrip, mesh=mesh,
                      in_specs=(layout.param_in_specs(),),
                      out_specs=layout.param_out_specs(),
                      axis_names={"data", "model"})
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     out = jax.jit(f)(params)
 for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(out)):
     np.testing.assert_allclose(np.asarray(a, np.float32),

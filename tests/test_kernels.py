@@ -1,4 +1,8 @@
-"""Pallas kernels vs pure-jnp oracles: shape × dtype sweeps (interpret)."""
+"""Pallas kernels vs pure-jnp oracles: shape × dtype sweeps (interpret).
+
+The single-node sweeps run the level kernels at W = 1 (one lane) against
+the per-node oracles of :mod:`repro.kernels.ref`.
+"""
 
 import jax
 import jax.numpy as jnp
@@ -15,6 +19,16 @@ def _vec(key, d, dtype):
     return jax.random.normal(key, (d,), jnp.float32).astype(dtype)
 
 
+def _one(*xs):
+    """A single node as a one-lane level: [d] → [1, d], scalars → [1]."""
+    return tuple(jnp.reshape(jnp.asarray(x), (1, -1) if jnp.ndim(x) else (1,))
+                 for x in xs)
+
+
+def _row(out):
+    return tuple(o[0] for o in out)
+
+
 def _tols(dtype):
     # bf16 outputs differ by one quantum when ref/kernel f32 intermediates
     # round to adjacent bf16 values
@@ -29,7 +43,7 @@ def test_count_ge_sweep(d, dtype):
     x = _vec(jax.random.PRNGKey(d), d, dtype)
     taus = jnp.linspace(0.01, 2.5, 32)
     np.testing.assert_array_equal(
-        np.asarray(ops.count_ge(x, taus, mode="always")),
+        np.asarray(ops.count_ge_level(*_one(x, taus), mode="always")[0]),
         np.asarray(ref.ref_count_ge(x, taus)))
 
 
@@ -42,7 +56,8 @@ def test_sparsify_ef_sweep(d, dtype):
     mask = (jax.random.uniform(k3, (d,)) < 0.02).astype(jnp.float32)
     w, tau = jnp.float32(1.7), jnp.float32(1.2)
     r = ref.ref_sparsify_ef(g, e, mask, w, tau)
-    p = ops.sparsify_ef(g, e, mask, w, tau, mode="always")
+    p = _row(ops.sparsify_ef_level(*_one(g, e, mask, w, tau, 1.0),
+                                   mode="always"))
     np.testing.assert_allclose(np.asarray(r[0], np.float32),
                                np.asarray(p[0], np.float32), **_tols(dtype))
     np.testing.assert_allclose(np.asarray(r[1], np.float32),
@@ -57,7 +72,7 @@ def test_chain_accum_sweep(d, dtype):
     gamma = _vec(k1, d, dtype) * (jax.random.uniform(k2, (d,)) < 0.05)
     gbar = _vec(k2, d, dtype) * (jax.random.uniform(k1, (d,)) < 0.05)
     r = ref.ref_chain_accum(gamma, gbar)
-    p = ops.chain_accum(gamma, gbar, mode="always")
+    p = _row(ops.chain_accum_level(*_one(gamma, gbar, 1.0), mode="always"))
     np.testing.assert_allclose(np.asarray(r[0], np.float32),
                                np.asarray(p[0], np.float32), **_tols(dtype))
     assert int(r[1]) == int(p[1])
@@ -70,7 +85,8 @@ def test_cl_fuse_sweep(d, dtype):
     g, e, gi = (_vec(k, d, dtype) for k in ks)
     w, tau = jnp.float32(0.8), jnp.float32(1.4)
     r = ref.ref_cl_fuse(g, e, gi, w, tau)
-    p = ops.cl_fuse(g, e, gi, w, tau, mode="always")
+    p = _row(ops.cl_fuse_level(*_one(g, e, gi, w, tau, 1.0, 1.0),
+                               mode="always"))
     for a, b in zip(r[:2], p[:2]):
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32), **_tols(dtype))
@@ -83,7 +99,8 @@ def test_threshold_pipeline_with_pallas_counts():
     x = jax.random.normal(jax.random.PRNGKey(7), (50_000,))
     for q in (10, 500, 5000):
         tau = sp.threshold_for_topq(
-            x, q, count_fn=lambda m, t: ops.count_ge(m, t, mode="always"))
+            x, q, count_fn=lambda m, t: ops.count_ge_level(
+                *_one(m, t), mode="always")[0])
         kept = int(jnp.sum(jnp.abs(x) >= tau))
         assert q <= kept <= q + max(2, int(0.02 * x.size))
 
@@ -91,7 +108,7 @@ def test_threshold_pipeline_with_pallas_counts():
 def test_mode_never_uses_ref():
     x = jnp.ones((100,))
     taus = jnp.asarray([0.5, 1.5])
-    out = ops.count_ge(x, taus, mode="never")
+    out = ops.count_ge_level(*_one(x, taus), mode="never")[0]
     np.testing.assert_array_equal(np.asarray(out), [100, 0])
 
 

@@ -72,7 +72,7 @@ tc = TrainConfig(agg=AggConfig(kind=AggKind.CL_SIA, q=1),
                  opt=OptConfig(name="adamw", lr=1e-3), q_frac=0.05,
                  agg_dtype="float32", ef_dtype="float32")
 
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     st = jax.device_put(init_state(cfg, tc, mesh, jax.random.PRNGKey(0)),
                         state_shardings(cfg, tc, mesh))
     plain = jax.jit(build_train_step(cfg, tc, mesh))

@@ -84,7 +84,7 @@ batch = {"tokens": toks, "labels": jnp.roll(toks, -1, axis=1)}
 tc = TrainConfig(agg=AggConfig(kind=AggKind.CL_SIA, q=1),
                  opt=OptConfig(name="adamw", lr=1e-3), q_frac=0.05,
                  agg_dtype="float32", ef_dtype="float32")
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     st = jax.device_put(init_state(cfg, tc, mesh, jax.random.PRNGKey(0)),
                         state_shardings(cfg, tc, mesh))
     step = jax.jit(build_train_step(cfg, tc, mesh))
@@ -99,7 +99,7 @@ assert float(m["agg_bits"]) > 0
 tc2 = TrainConfig(agg=AggConfig(kind=AggKind.DENSE_IA, q=1),
                   opt=OptConfig(name="adamw", lr=1e-3),
                   agg_dtype="float32", ef_dtype="float32")
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     st2 = jax.device_put(init_state(cfg, tc2, mesh, jax.random.PRNGKey(0)),
                          state_shardings(cfg, tc2, mesh))
     s2, _ = jax.jit(build_train_step(cfg, tc2, mesh))(st2, dict(batch))
@@ -119,7 +119,7 @@ assert err < 3e-5, err
 tc3 = TrainConfig(agg=AggConfig(kind=AggKind.CL_TC_SIA, q=10),
                   opt=OptConfig(name="sgd", lr=1e-2), q_frac=0.05,
                   agg_dtype="float32", ef_dtype="float32")
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     st3 = jax.device_put(init_state(cfg, tc3, mesh, jax.random.PRNGKey(0)),
                          state_shardings(cfg, tc3, mesh))
     step3 = jax.jit(build_train_step(cfg, tc3, mesh))
@@ -129,7 +129,7 @@ assert np.isfinite(m3["loss"]) and float(m3["agg_bits"]) > 0
 
 # 4) straggler round: participation mask, loss still finite, EF grows
 tc4 = tc
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     st4 = jax.device_put(init_state(cfg, tc4, mesh, jax.random.PRNGKey(0)),
                          state_shardings(cfg, tc4, mesh))
     step4 = jax.jit(build_train_step(cfg, tc4, mesh))
