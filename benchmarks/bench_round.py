@@ -617,7 +617,9 @@ def main(argv=None) -> dict:
     if args.trace:
         from repro.obs.collector import TraceCollector
         with TraceCollector(args.trace, meta=dict(result["meta"])) as col:
-            timer.emit(col)
+            for s in timer.spans:
+                col.record_span(s.name, s.t0_s, s.dur_s, track=s.track,
+                                args=s.args)
     with open(args.out, "w") as f:
         json.dump(result, f, indent=1, sort_keys=True)
         f.write("\n")

@@ -60,6 +60,7 @@ from repro.core import sparsify as sp
 from repro.core.algorithms import (AggConfig, AggKind, HopStats, NodeCtx,
                                    level_step, level_step_batched, node_step)
 from repro.core.ring import RingStats
+from repro.obs import scopes
 
 Array = jax.Array
 
@@ -168,6 +169,7 @@ def _nest_vmap(fn, levels: int):
     return fn
 
 
+@jax.named_scope(scopes.TRANSPORT)
 def _send_static(cfg: AggConfig, payload: Array, seg: int, axis,
                  shift: int, compact: bool) -> Array:
     """One logical hop by a static ring shift (the ring's ``_send``).
@@ -192,6 +194,7 @@ def _send_static(cfg: AggConfig, payload: Array, seg: int, axis,
         lead)(vals, idx)
 
 
+@jax.named_scope(scopes.TRANSPORT)
 def _route_butterfly(cfg: AggConfig, payload: Array, offsets: Array,
                      seg: int, axis, compact: bool) -> Array:
     """Deliver ``payload[w]`` to rank ``r + offsets[w]`` for every rank r.
@@ -544,25 +547,26 @@ def run_plan_clients_local(
         my_stats = jax.tree.map(
             lambda acc, s: jnp.where(active, s, acc), my_stats, st)
 
-        payload = gamma_out * active.astype(gamma_out.dtype)
-        if compact:
-            vals, idx, _ = sp.compact(payload, q_wire)
-            all_vals = jax.lax.all_gather(
-                vals.astype(jnp.dtype(cfg.wire_dtype)), axis)
-            all_idx = jax.lax.all_gather(idx, axis)
-            def from_rank(b):
-                return sp.scatter(all_vals[b].astype(payload.dtype),
-                                  all_idx[b], d)
-        else:
-            all_pay = jax.lax.all_gather(payload, axis)  # [K, d]
-            def from_rank(b):
-                return all_pay[b]
+        with jax.named_scope(scopes.TRANSPORT):
+            payload = gamma_out * active.astype(gamma_out.dtype)
+            if compact:
+                vals, idx, _ = sp.compact(payload, q_wire)
+                all_vals = jax.lax.all_gather(
+                    vals.astype(jnp.dtype(cfg.wire_dtype)), axis)
+                all_idx = jax.lax.all_gather(idx, axis)
+                def from_rank(b):
+                    return sp.scatter(all_vals[b].astype(payload.dtype),
+                                      all_idx[b], d)
+            else:
+                all_pay = jax.lax.all_gather(payload, axis)  # [K, d]
+                def from_rank(b):
+                    return all_pay[b]
 
-        # deliver in slot order (the host executor's scatter order): row 0
-        # if the sender's parent is me, rows 1..R if it is a sink, else
-        # trash.
-        b_clip = jnp.clip(ids_l, 0, K - 1)
-        arrived = jax.vmap(from_rank)(b_clip) * slot_mask[l][:, None]
+            # deliver in slot order (the host executor's scatter order): row 0
+            # if the sender's parent is me, rows 1..R if it is a sink, else
+            # trash.
+            b_clip = jnp.clip(ids_l, 0, K - 1)
+            arrived = jax.vmap(from_rank)(b_clip) * slot_mask[l][:, None]
         par_l = parent_row[l]
         p_clients = plan.num_clients
         rows = jnp.where(
@@ -935,23 +939,24 @@ def run_plan_clients_batched(
         my_stats = jax.tree.map(
             lambda acc, s: jnp.where(active, s, acc), my_stats, st)
 
-        payload = gamma_out * active[:, None].astype(gamma_out.dtype)
-        b_clip = jnp.clip(ids_l, 0, K - 1)               # [B, W]
-        if compact:
-            vals, idx, _ = jax.vmap(
-                lambda x: sp.compact(x, q_wire))(payload)
-            all_vals = jax.lax.all_gather(
-                vals.astype(jnp.dtype(cfg.wire_dtype)), axis)  # [K, B, q]
-            all_idx = jax.lax.all_gather(idx, axis)
-            sel = lambda a: jnp.take_along_axis(
-                jnp.moveaxis(a, 0, 1), b_clip[:, :, None], axis=1)
-            arrived = jax.vmap(jax.vmap(
-                lambda v, i: sp.scatter(v.astype(payload.dtype), i, d)))(
-                    sel(all_vals), sel(all_idx))         # [B, W, d]
-        else:
-            all_pay = jax.lax.all_gather(payload, axis)  # [K, B, d]
-            arrived = jnp.take_along_axis(
-                jnp.moveaxis(all_pay, 0, 1), b_clip[:, :, None], axis=1)
+        with jax.named_scope(scopes.TRANSPORT):
+            payload = gamma_out * active[:, None].astype(gamma_out.dtype)
+            b_clip = jnp.clip(ids_l, 0, K - 1)               # [B, W]
+            if compact:
+                vals, idx, _ = jax.vmap(
+                    lambda x: sp.compact(x, q_wire))(payload)
+                all_vals = jax.lax.all_gather(
+                    vals.astype(jnp.dtype(cfg.wire_dtype)), axis)  # [K, B, q]
+                all_idx = jax.lax.all_gather(idx, axis)
+                sel = lambda a: jnp.take_along_axis(
+                    jnp.moveaxis(a, 0, 1), b_clip[:, :, None], axis=1)
+                arrived = jax.vmap(jax.vmap(
+                    lambda v, i: sp.scatter(v.astype(payload.dtype), i, d)))(
+                        sel(all_vals), sel(all_idx))         # [B, W, d]
+            else:
+                all_pay = jax.lax.all_gather(payload, axis)  # [K, B, d]
+                arrived = jnp.take_along_axis(
+                    jnp.moveaxis(all_pay, 0, 1), b_clip[:, :, None], axis=1)
         arrived = arrived * mask_l[:, :, None]
         p_clients = plan.num_clients
         rows = jnp.where(

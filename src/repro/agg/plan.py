@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.core.algorithms import (AggConfig, AggKind, HopStats, level_step,
                                    level_step_batched)
+from repro.obs import scopes
 from repro.topo.tree import PS, AggTree, build_schedule, path_tree
 
 Array = jax.Array
@@ -250,6 +251,7 @@ def _take_rows(x: Array, ids: Array) -> Array:
                       for i in range(ids.shape[0])])
 
 
+@jax.named_scope(scopes.EXECUTOR)
 def execute(
     cfg: AggConfig,
     plan: AggPlan,
@@ -357,6 +359,7 @@ def stack_plans(plans: Sequence[AggPlan]) -> AggPlan:
                    num_clients=p0.num_clients, num_sinks=p0.num_sinks)
 
 
+@jax.named_scope(scopes.EXECUTOR)
 def execute_batched(
     cfg: AggConfig,
     plan: AggPlan,

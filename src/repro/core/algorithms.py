@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from repro.core import sparsify as sp
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
+from repro.obs import scopes
 
 Array = jax.Array
 
@@ -276,6 +277,10 @@ def _lane_inf(w: int) -> Array:
     return jnp.full((w,), jnp.inf, jnp.float32)
 
 
+_tau_scope = jax.named_scope(scopes.TAU_SEARCH)
+_node_scope = jax.named_scope(scopes.NODE_STEP)
+
+
 def _tau_operand(cfg: AggConfig, g, e, gam, w, p, gm=None, cohorts=0, *,
                  include_gamma: bool = False) -> sp.TauOperand:
     """Build the level's bisection operand from the raw node inputs.
@@ -291,16 +296,19 @@ def _tau_operand(cfg: AggConfig, g, e, gam, w, p, gm=None, cohorts=0, *,
     """
     mode = cfg.kernel_mode
 
+    @_tau_scope
     def materialize():
         return kref.fused_operand(g, e, gam, w, p, gm,
                                   include_gamma=include_gamma,
                                   gmask_cohorts=cohorts)
 
+    @_tau_scope
     def count(taus):
         return kops.count_ge_fused_level(
             g, e, gam, w, p, taus, gm, include_gamma=include_gamma,
             gmask_cohorts=cohorts, mode=mode)
 
+    @_tau_scope
     def max_abs():
         # XLA fuses the elementwise operand into the reduce — one streaming
         # pass, no [W, d] landing in HBM; bitwise equal to a materialized
@@ -310,6 +318,7 @@ def _tau_operand(cfg: AggConfig, g, e, gam, w, p, gm=None, cohorts=0, *,
             return jnp.zeros(mag.shape[:-1], jnp.float32)
         return jnp.max(mag, axis=-1)
 
+    @_tau_scope
     def hist(tables):
         return kops.hist_topq_level(
             g, e, gam, w, p, tables, gm, include_gamma=include_gamma,
@@ -494,6 +503,7 @@ _FUSED_LEVEL = {
 }
 
 
+@_node_scope
 def _run_fused_level(cfg, g, gamma_in, e, weight, participate, global_mask,
                      q_budget, valid, cohorts=0):
     w_lanes = g.shape[0]
@@ -572,6 +582,7 @@ def _finalize(cfg: AggConfig, d: int, gamma_out: Array, e_new: Array,
     return gamma_out, e_new, stats
 
 
+@_node_scope
 def step_sia(cfg: AggConfig, g: Array, gamma_in: Array, e: Array,
              weight: Array, ctx: NodeCtx) -> tuple[Array, Array, HopStats]:
     """Alg 1 — SoA sparse IA: local Top-Q then add."""
@@ -587,6 +598,7 @@ def step_sia(cfg: AggConfig, g: Array, gamma_in: Array, e: Array,
     return _finalize(cfg, d, gamma_out, e_new, jnp.zeros_like(g))
 
 
+@_node_scope
 def step_re_sia(cfg: AggConfig, g: Array, gamma_in: Array, e: Array,
                 weight: Array, ctx: NodeCtx) -> tuple[Array, Array, HopStats]:
     """Alg 2 — reduced-error: transmit inside union(local Top-Q, incoming)."""
@@ -604,6 +616,7 @@ def step_re_sia(cfg: AggConfig, g: Array, gamma_in: Array, e: Array,
     return _finalize(cfg, d, gamma_out, e_new, jnp.zeros_like(g))
 
 
+@_node_scope
 def step_cl_sia(cfg: AggConfig, g: Array, gamma_in: Array, e: Array,
                 weight: Array, ctx: NodeCtx) -> tuple[Array, Array, HopStats]:
     """Alg 3 — constant-length: aggregate then Top-Q. ‖γ_out‖₀ ≤ Q."""
@@ -623,6 +636,7 @@ def step_cl_sia(cfg: AggConfig, g: Array, gamma_in: Array, e: Array,
     return _finalize(cfg, d, gamma_out, e_new, jnp.zeros_like(g))
 
 
+@_node_scope
 def step_tc_sia(cfg: AggConfig, g: Array, gamma_in: Array, e: Array,
                 weight: Array, ctx: NodeCtx) -> tuple[Array, Array, HopStats]:
     """Alg 4 — time-correlated sparse IA (global mask + Q_L local + incoming)."""
@@ -641,6 +655,7 @@ def step_tc_sia(cfg: AggConfig, g: Array, gamma_in: Array, e: Array,
     return _finalize(cfg, d, gamma_out, e_new, m)
 
 
+@_node_scope
 def step_cl_tc_sia(cfg: AggConfig, g: Array, gamma_in: Array, e: Array,
                    weight: Array, ctx: NodeCtx) -> tuple[Array, Array, HopStats]:
     """Alg 5 — constant-length time-correlated: CL-SIA on the off-mask part.
@@ -666,6 +681,7 @@ def step_cl_tc_sia(cfg: AggConfig, g: Array, gamma_in: Array, e: Array,
     return _finalize(cfg, d, gamma_out, e_new, m)
 
 
+@_node_scope
 def step_dense_ia(cfg: AggConfig, g: Array, gamma_in: Array, e: Array,
                   weight: Array, ctx: NodeCtx) -> tuple[Array, Array, HopStats]:
     """IA without sparsification — the efficiency upper baseline (Fig 2b)."""

@@ -24,6 +24,8 @@ from typing import Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs import scopes
+
 Array = jax.Array
 
 
@@ -31,6 +33,7 @@ Array = jax.Array
 # Exact Top-Q (oracle)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope(scopes.TAU_SEARCH)
 def topq(x: Array, q: int) -> Array:
     """``S(x, Q)``: keep the Q largest-magnitude entries of ``x``, zero the rest.
 
@@ -48,6 +51,7 @@ def topq(x: Array, q: int) -> Array:
     return jnp.where(mask, x, 0)
 
 
+@jax.named_scope(scopes.TAU_SEARCH)
 def topq_mask(x: Array, q: int) -> Array:
     """``s(x, Q)``: the 0/1 float mask of the Top-Q support of ``x``."""
     if q <= 0:
@@ -82,6 +86,7 @@ def nnz(x: Array) -> Array:
 # Dynamic-budget Top-Q (traced q — per-node bandwidth-aware budgets)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope(scopes.TAU_SEARCH)
 def _dynamic_keep(x: Array, q: Array) -> Array:
     """Boolean Top-q support of ``x`` for a *traced* budget ``q``.
 
@@ -342,6 +347,7 @@ def _hist_bisect(new_lo: Array, w2: Array, D2: Array, F: Array, q: int,
     return jnp.maximum(tau, 1e-30), counts
 
 
+@jax.named_scope(scopes.TAU_SEARCH)
 def threshold_for_topq(
     x: Optional[Array],
     q: int,

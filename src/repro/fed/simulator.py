@@ -30,7 +30,6 @@ differently when a shard_map consumes it.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable, NamedTuple, Optional
 
 import jax
@@ -45,6 +44,7 @@ from repro.core import tcs as tcs_mod
 from repro.core.algorithms import AggConfig, AggKind
 from repro.data.federated import FederatedData, client_minibatch
 from repro.fed.topology import FailureSchedule, TreeTopology
+from repro.obs import scopes
 from repro.obs.collector import RoundBuffer, TraceCounter
 from repro.obs.timing import PhaseTimer
 from repro.runtime.fault import banked_mass, dead_banked_mass
@@ -285,60 +285,64 @@ class Simulator:
         def one_round(state: SimState, plan: AggPlan,
                       participate: Optional[Array] = None):
             trace_counter.bump()        # runs at trace time only
-            rng, kb = jax.random.split(state.rng)
-            params = unflatten_lr(state.flat_w, pc)
-            bx, by = client_minibatch(fed, kb, pc.batch_size)
+            with jax.named_scope(scopes.GRADS):
+                rng, kb = jax.random.split(state.rng)
+                params = unflatten_lr(state.flat_w, pc)
+                bx, by = client_minibatch(fed, kb, pc.batch_size)
 
-            # local SGD step per client → effective gradients
-            def client_grad(x, y):
-                g = jax.grad(lr_loss)(params, x, y)
-                return -lr * flatten_lr(g)          # g_k = w_k − w
+                # local SGD step per client → effective gradients
+                def client_grad(x, y):
+                    g = jax.grad(lr_loss)(params, x, y)
+                    return -lr * flatten_lr(g)          # g_k = w_k − w
 
-            g = jax.vmap(client_grad)(bx, by)        # [K, d]
+                g = jax.vmap(client_grad)(bx, by)        # [K, d]
 
-            global_mask = None
-            tcs_prev = state.tcs_prev
-            if needs_tcs:
-                global_mask = tcs_mod.global_mask(
-                    tcs_mod.TCSState(tcs_prev), state.flat_w,
-                    agg_cfg.q_global)
-                tcs_prev = state.flat_w
+            with jax.named_scope(scopes.AGGREGATE):
+                global_mask = None
+                tcs_prev = state.tcs_prev
+                if needs_tcs:
+                    global_mask = tcs_mod.global_mask(
+                        tcs_mod.TCSState(tcs_prev), state.flat_w,
+                        agg_cfg.q_global)
+                    tcs_prev = state.flat_w
 
-            nested = isinstance(plan, NestedPlan)
-            if nested:
-                res = run_nested(agg_cfg, plan, g, state.ef, weights,
-                                 stage_e=state.stage_ef,
-                                 global_mask=global_mask,
-                                 participate=participate)
-                stage_ef = res.stage_e_new
-                all_stats = (res.stats,) + res.stage_stats
-                # whole-chain aliveness: a stub cluster's clients forward
-                # nothing to the PS, so they must leave the denominator too
-                alive = jnp.asarray(plan.client_alive(), weights.dtype)
-            else:
-                res = run_round(agg_cfg, plan, g, state.ef, weights,
-                                global_mask=global_mask,
-                                participate=participate)
-                stage_ef = state.stage_ef
-                all_stats = (res.stats,)
-                alive = jnp.asarray(plan.alive, weights.dtype)
+                nested = isinstance(plan, NestedPlan)
+                if nested:
+                    res = run_nested(agg_cfg, plan, g, state.ef, weights,
+                                     stage_e=state.stage_ef,
+                                     global_mask=global_mask,
+                                     participate=participate)
+                    stage_ef = res.stage_e_new
+                    all_stats = (res.stats,) + res.stage_stats
+                    # whole-chain aliveness: a stub cluster's clients forward
+                    # nothing to the PS, so they must leave the denominator too
+                    alive = jnp.asarray(plan.client_alive(), weights.dtype)
+                else:
+                    res = run_round(agg_cfg, plan, g, state.ef, weights,
+                                    global_mask=global_mask,
+                                    participate=participate)
+                    stage_ef = state.stage_ef
+                    all_stats = (res.stats,)
+                    alive = jnp.asarray(plan.alive, weights.dtype)
 
-            part = alive if participate is None else participate * alive
-            d_total = jnp.maximum(jnp.sum(weights * part), 1e-9)
-            flat_new = state.flat_w + res.aggregate / d_total
+                part = alive if participate is None else participate * alive
+                d_total = jnp.maximum(jnp.sum(weights * part), 1e-9)
+                flat_new = state.flat_w + res.aggregate / d_total
 
-            new_state = self._pin(SimState(
-                round=state.round + 1, flat_w=flat_new, ef=res.e_new,
-                tcs_prev=tcs_prev, rng=rng, stage_ef=stage_ef))
-            # telemetry riders — tiny [K] reductions of state the round
-            # already holds, always computed so collection on/off cannot
-            # change the jitted program
-            ef_mass = banked_mass(res.e_new)
-            ef_dead = dead_banked_mass(res.e_new, part)
+                new_state = self._pin(SimState(
+                    round=state.round + 1, flat_w=flat_new, ef=res.e_new,
+                    tcs_prev=tcs_prev, rng=rng, stage_ef=stage_ef))
+                # telemetry riders — tiny [K] reductions of state the round
+                # already holds, always computed so collection on/off cannot
+                # change the jitted program
+                ef_mass = banked_mass(res.e_new)
+                ef_dead = dead_banked_mass(res.e_new, part)
+            with jax.named_scope(scopes.EVAL):
+                loss = lr_loss(unflatten_lr(flat_new, pc),
+                               fed.x.reshape(-1, pc.input_dim),
+                               fed.y.reshape(-1))
             log = RoundLog(
-                loss=lr_loss(unflatten_lr(flat_new, pc),
-                             fed.x.reshape(-1, pc.input_dim),
-                             fed.y.reshape(-1)),
+                loss=loss,
                 stats=all_stats,
                 participation=part,
                 ef_mass=ef_mass,
@@ -398,56 +402,63 @@ class Simulator:
         def one_round(state: SimState, plan: AggPlan,
                       participate: Optional[Array] = None):
             trace_counter.bump()        # runs at trace time only
-            b = state.flat_w.shape[0]
-            keys = jax.vmap(jax.random.split)(state.rng)   # [B, 2, 2]
-            rng, kb = keys[:, 0], keys[:, 1]
+            with jax.named_scope(scopes.GRADS):
+                b = state.flat_w.shape[0]
+                keys = jax.vmap(jax.random.split)(state.rng)   # [B, 2, 2]
+                rng, kb = keys[:, 0], keys[:, 1]
 
-            def cohort_grads(flat_w, key):
-                params = unflatten_lr(flat_w, pc)
-                bx, by = client_minibatch(fed, key, pc.batch_size)
+                def cohort_grads(flat_w, key):
+                    params = unflatten_lr(flat_w, pc)
+                    bx, by = client_minibatch(fed, key, pc.batch_size)
 
-                def client_grad(x, y):
-                    gr = jax.grad(lr_loss)(params, x, y)
-                    return -lr * flatten_lr(gr)
+                    def client_grad(x, y):
+                        gr = jax.grad(lr_loss)(params, x, y)
+                        return -lr * flatten_lr(gr)
 
-                return jax.vmap(client_grad)(bx, by)
+                    return jax.vmap(client_grad)(bx, by)
 
-            g = jax.vmap(cohort_grads)(state.flat_w, kb)   # [B, K, d]
+                g = jax.vmap(cohort_grads)(state.flat_w, kb)   # [B, K, d]
 
-            global_mask = None
-            tcs_prev = state.tcs_prev
-            if needs_tcs:
-                global_mask = jax.vmap(
-                    lambda prev, w: tcs_mod.global_mask(
-                        tcs_mod.TCSState(prev), w, agg_cfg.q_global))(
-                            tcs_prev, state.flat_w)        # [B, d]
-                tcs_prev = state.flat_w
+            with jax.named_scope(scopes.AGGREGATE):
+                global_mask = None
+                tcs_prev = state.tcs_prev
+                if needs_tcs:
+                    global_mask = jax.vmap(
+                        lambda prev, w: tcs_mod.global_mask(
+                            tcs_mod.TCSState(prev), w, agg_cfg.q_global))(
+                                tcs_prev, state.flat_w)        # [B, d]
+                    tcs_prev = state.flat_w
 
-            res = run_batch(agg_cfg, plan, g, state.ef,
-                            jnp.broadcast_to(weights, (b, k)),
-                            global_mask=global_mask,
-                            participate=participate)
+                res = run_batch(agg_cfg, plan, g, state.ef,
+                                jnp.broadcast_to(weights, (b, k)),
+                                global_mask=global_mask,
+                                participate=participate)
 
-            alive = jnp.asarray(plan.alive, weights.dtype)
-            alive = jnp.broadcast_to(alive, (b, k))        # [K] | [B, K]
-            part = alive if participate is None else participate * alive
-            d_total = jnp.maximum(
-                jnp.sum(weights * part, axis=1), 1e-9)     # [B]
-            flat_new = state.flat_w + res.aggregate / d_total[:, None]
+                alive = jnp.asarray(plan.alive, weights.dtype)
+                alive = jnp.broadcast_to(alive, (b, k))        # [K] | [B, K]
+                part = alive if participate is None else participate * alive
+                d_total = jnp.maximum(
+                    jnp.sum(weights * part, axis=1), 1e-9)     # [B]
+                flat_new = state.flat_w + res.aggregate / d_total[:, None]
 
-            new_state = self._pin(SimState(
-                round=state.round + 1, flat_w=flat_new, ef=res.e_new,
-                tcs_prev=tcs_prev, rng=rng))
-            xs = fed.x.reshape(-1, pc.input_dim)
-            ys = fed.y.reshape(-1)
+                new_state = self._pin(SimState(
+                    round=state.round + 1, flat_w=flat_new, ef=res.e_new,
+                    tcs_prev=tcs_prev, rng=rng))
+            with jax.named_scope(scopes.EVAL):
+                xs = fed.x.reshape(-1, pc.input_dim)
+                ys = fed.y.reshape(-1)
+                loss = jax.vmap(lambda w: lr_loss(unflatten_lr(w, pc),
+                                                  xs, ys))(flat_new)
+            with jax.named_scope(scopes.AGGREGATE):
+                ef_mass = banked_mass(res.e_new)           # [B, K]
+                ef_dead = jax.vmap(dead_banked_mass)(res.e_new, part)
             log = RoundLog(
-                loss=jax.vmap(lambda w: lr_loss(unflatten_lr(w, pc),
-                                                xs, ys))(flat_new),
+                loss=loss,
                 stats=(res.stats,),                        # leaves [B, K]
                 participation=part,
-                ef_mass=banked_mass(res.e_new),            # [B, K]
+                ef_mass=ef_mass,
                 stage_ef_mass=(),
-                ef_dead_mass=jax.vmap(dead_banked_mass)(res.e_new, part),
+                ef_dead_mass=ef_dead,
             )
             return new_state, log
 
@@ -526,14 +537,13 @@ class Simulator:
         buf = RoundBuffer()
         pending: list = []
         accs, losses, bits, nnzs = [], [], [], []
-        run_t0 = time.perf_counter()
 
         def flush():
-            t0 = time.perf_counter()
-            logs = _fetch_logs(buf)
-            dur = time.perf_counter() - t0
+            # a span of its own on the trace, not a phase of the next round
+            with timer.phase("flush", track="simulator", keep=False) as span:
+                logs = _fetch_logs(buf)
             if collector is not None and logs:
-                collector.record_span("flush", t0 - run_t0, dur,
+                collector.record_span("flush", span.t0_s, span.dur_s,
                                       track="simulator",
                                       args={"rounds": len(logs)})
             for (log, acc), (r, plan, tree, retraces, phases) in zip(
@@ -559,14 +569,14 @@ class Simulator:
             del pending[:]
 
         for r in range(rounds):
-            with timer.phase("plan"):
+            with timer.phase("plan", track="simulator"):
                 plan, tree = plan_for(r, state)
                 part = None
                 if participate_fn is not None:
                     part = jnp.asarray(participate_fn(r, state))
                     if part.ndim == 1:     # one mask for every cohort
                         part = jnp.broadcast_to(part, (b, self.k))
-            with timer.phase("dispatch"):
+            with timer.phase("dispatch", track="simulator"):
                 state, log = step(state, plan, part)
                 acc = None
                 if test_x is not None and (r % eval_every == 0
@@ -711,14 +721,13 @@ class Simulator:
         buf = RoundBuffer()
         pending: list = []      # (round, plan, tree, retraces, phases)
         accs, losses, bits, nnzs = [], [], [], []
-        run_t0 = time.perf_counter()
 
         def flush():
-            t0 = time.perf_counter()
-            logs = _fetch_logs(buf)
-            dur = time.perf_counter() - t0
+            # a span of its own on the trace, not a phase of the next round
+            with timer.phase("flush", track="simulator", keep=False) as span:
+                logs = _fetch_logs(buf)
             if collector is not None and logs:
-                collector.record_span("flush", t0 - run_t0, dur,
+                collector.record_span("flush", span.t0_s, span.dur_s,
                                       track="simulator",
                                       args={"rounds": len(logs)})
             for (log, acc), (r, plan, tree, retraces, phases) in zip(
@@ -740,7 +749,7 @@ class Simulator:
             del pending[:]
 
         for r in range(rounds):
-            with timer.phase("plan"):
+            with timer.phase("plan", track="simulator"):
                 plan, tree = plan_for(r, state)
                 part = None
                 if compiled is not None:
@@ -748,7 +757,7 @@ class Simulator:
                 elif participate_fn is not None:
                     part = participate_fn(r, state)
             # stranded/dead clients are masked inside execute via plan.alive
-            with timer.phase("dispatch"):
+            with timer.phase("dispatch", track="simulator"):
                 state, log = step(state, plan, part)
                 acc = None
                 if test_x is not None and (r % eval_every == 0

@@ -57,12 +57,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.obs import scopes
+
 SUBLANES = 8
 LANES = 1024
 BLOCK = SUBLANES * LANES
 VREG = 128                  # lanes of one vector register
 
 
+# The wrappers' pads into the kernels' blocks and the slices back out run
+# under the kernel_layout scope; the pallas_calls stay in the caller's.
+_layout_scope = jax.named_scope(scopes.KERNEL_LAYOUT)
+
+
+@_layout_scope
 def _pad_lanes(v: jax.Array, n_blocks: int, pad: int):
     """[W, d] → [W, n_blocks, SUBLANES, LANES] (zero padded)."""
     w = v.shape[0]
@@ -70,6 +78,7 @@ def _pad_lanes(v: jax.Array, n_blocks: int, pad: int):
         w, n_blocks, SUBLANES, LANES)
 
 
+@_layout_scope
 def _pad_shared(v: jax.Array, n_blocks: int, pad: int):
     """[d] → [n_blocks, SUBLANES, LANES] (zero padded) — a lane-shared
     operand streamed once per block instead of once per (lane, block)."""
@@ -141,11 +150,27 @@ def _count(pred):
     return _fold(pred.astype(jnp.int32))
 
 
+@_layout_scope
 def _counts(acc):
     """Per-lane totals of ``_count`` accumulator tiles."""
     return jnp.sum(acc, axis=(-2, -1), dtype=jnp.int32)
 
 
+@_layout_scope
+def _lane_err(acc):
+    """Per-lane ‖e'‖² of :func:`_pinned_tile_err` tiles (every element of
+    a lane's tile holds its sum)."""
+    return acc[:, 0, 0]
+
+
+@_layout_scope
+def _unpad(x, w_lanes: int, d: int):
+    """[W, n_blocks, SUBLANES, LANES] → [W, d]: the inverse of
+    :func:`_pad_lanes`."""
+    return x.reshape(w_lanes, -1)[:, :d]
+
+
+@_layout_scope
 def _gmask_operand(gmask, w_lanes: int, gmask_cohorts: int, n_blocks: int,
                    pad: int):
     """Pick the (padded operand, block spec) for a TC global mask.
@@ -166,6 +191,7 @@ def _gmask_operand(gmask, w_lanes: int, gmask_cohorts: int, n_blocks: int,
     return _pad_lanes(gmask.astype(jnp.float32), n_blocks, pad), _blk()
 
 
+@_layout_scope
 def _drop_pad_level(counts, taus, pad: int):
     """Subtract the zero-padding contribution from per-lane counts.
 
@@ -292,9 +318,9 @@ def sparsify_ef_level_pallas(g, e, mask_in, weight, tau, valid, *,
         interpret=interpret,
     )(*operands)
     gbar, e_new, nnz = out[:3]
-    res = (gbar.reshape(w_lanes, -1)[:, :d],
-           e_new.reshape(w_lanes, -1)[:, :d], _counts(nnz))
-    return res + (out[3][:, 0, 0],) if with_err else res
+    res = (_unpad(gbar, w_lanes, d), _unpad(e_new, w_lanes, d),
+           _counts(nnz))
+    return res + (_lane_err(out[3]),) if with_err else res
 
 
 # ---------------------------------------------------------------------------
@@ -371,7 +397,7 @@ def chain_accum_level_pallas(gamma_in, gbar, valid, gmask=None, *,
         ],
         interpret=interpret,
     )(*operands)
-    return gout.reshape(w_lanes, -1)[:, :d], _counts(nnz), _counts(nnz_off)
+    return _unpad(gout, w_lanes, d), _counts(nnz), _counts(nnz_off)
 
 
 # ---------------------------------------------------------------------------
@@ -502,10 +528,9 @@ def cl_fuse_level_pallas(g, e, gamma_in, weight, tau, participate, valid,
         interpret=interpret,
     )(*operands)
     gout, e_new, nnz, nnz_off = out[:4]
-    res = (gout.reshape(w_lanes, -1)[:, :d],
-           e_new.reshape(w_lanes, -1)[:, :d], _counts(nnz),
-           _counts(nnz_off))
-    return res + (out[4][:, 0, 0],) if with_err else res
+    res = (_unpad(gout, w_lanes, d), _unpad(e_new, w_lanes, d),
+           _counts(nnz), _counts(nnz_off))
+    return res + (_lane_err(out[4]),) if with_err else res
 
 
 # ---------------------------------------------------------------------------
@@ -770,4 +795,6 @@ def hist_topq_level_pallas(g, e, gamma_in, weight, participate, tables,
         out_shape=jax.ShapeDtypeStruct((grid[0], bins, bins), jnp.int32),
         interpret=interpret,
     )(*operands, *(t.astype(jnp.float32) for t in tables))
-    return hist[:, :branch + 1, :branch + 1], hist[:, :branch + 1, bins - 1]
+    with jax.named_scope(scopes.KERNEL_LAYOUT):
+        return (hist[:, :branch + 1, :branch + 1],
+                hist[:, :branch + 1, bins - 1])

@@ -6,17 +6,31 @@ times on an async backend is the dispatch (plus any blocking the caller
 does), which is exactly the honest host-side quantity; per-op device
 timings belong to the profiler, not the trace.
 
-Spans accumulate in memory; :meth:`PhaseTimer.emit` writes them to a
-:class:`~repro.obs.collector.TraceCollector` as ``span`` records (one
-Perfetto track per ``track`` name) and :meth:`PhaseTimer.totals` folds
-them into the per-phase seconds a round record carries.
+Each phase is also a ``jax.profiler.TraceAnnotation`` named
+``repro.<track>.<name>``, so a profiler trace shows the host spans on its
+own clock beside the device ops (``repro.simulator.flush`` covers the
+device→host log fetch). Spans accumulate in memory, on the timer's own
+origin; :meth:`PhaseTimer.totals` folds them into the per-phase seconds a
+round record carries.
 """
 
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import time
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
+
+
+@dataclasses.dataclass
+class Span:
+    name: str
+    track: str
+    t0_s: float             # from the timer's origin
+    dur_s: float = 0.0
+    args: Optional[dict] = None
 
 
 class PhaseTimer:
@@ -29,32 +43,30 @@ class PhaseTimer:
     """
 
     def __init__(self):
-        self.spans: list = []          # (name, track, t0_s, dur_s, args)
+        self.spans: list = []          # of Span
         self._origin = time.perf_counter()
 
     @contextlib.contextmanager
     def phase(self, name: str, *, track: str = "host",
-              args: Optional[dict] = None):
+              args: Optional[dict] = None, keep: bool = True):
+        """Time the block as one span, which the block receives. With
+        ``keep=False`` the span is not added to :attr:`spans`, so it
+        stays out of :meth:`totals`."""
         t0 = time.perf_counter()
+        span = Span(name, track, t0 - self._origin, args=args)
         try:
-            yield self
+            with TraceAnnotation(f"repro.{track}.{name}"):
+                yield span
         finally:
-            t1 = time.perf_counter()
-            self.spans.append((name, track, t0 - self._origin, t1 - t0,
-                               args))
-
-    def add(self, name: str, dur_s: float, *, track: str = "host",
-            args: Optional[dict] = None) -> None:
-        """Record an externally-measured duration at the current cursor."""
-        self.spans.append((name, track,
-                           time.perf_counter() - self._origin - dur_s,
-                           dur_s, args))
+            span.dur_s = time.perf_counter() - t0
+            if keep:
+                self.spans.append(span)
 
     def totals(self) -> dict:
         """Summed seconds per phase name."""
         out: dict = {}
-        for name, _, _, dur, _ in self.spans:
-            out[name] = out.get(name, 0.0) + dur
+        for s in self.spans:
+            out[s.name] = out.get(s.name, 0.0) + s.dur_s
         return out
 
     def take(self) -> dict:
@@ -62,12 +74,3 @@ class PhaseTimer:
         out = self.totals()
         self.spans = []
         return out
-
-    def emit(self, collector) -> int:
-        """Write every span to ``collector`` as ``span`` records."""
-        n = 0
-        for name, track, t0, dur, args in self.spans:
-            if collector.record_span(name, t0, dur, track=track,
-                                     args=args) is not None:
-                n += 1
-        return n

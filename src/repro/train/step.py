@@ -40,6 +40,7 @@ from repro.core.algorithms import AggConfig
 from repro.core.flat_layout import FlatLayout
 from repro.models import model as model_mod
 from repro.models import partition
+from repro.obs import scopes
 from repro.optim import optimizers as opt_mod
 from repro.optim.schedule import lr_schedule
 from repro.train.state import TrainConfig, TrainState
@@ -488,73 +489,78 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh,
             participate = jnp.ones((k_dp,), jnp.float32)
 
         # phase 1 — per-client grads (model axis auto inside)
-        grads_stacked, loss = compat.shard_map(
-            per_client,
-            mesh=mesh,
-            in_specs=(jax.tree.map(lambda _: P(), state.params),
-                      jax.tree.map(lambda l: P(dp, *([None] * (l.ndim - 1))),
-                                   batch)),
-            out_specs=(jax.tree.map(
-                lambda l: P(dp, *([None] * l.ndim)), state.params), P()),
-            axis_names=set(dp),
-        )(state.params, batch)
+        with jax.named_scope(scopes.GRADS):
+            grads_stacked, loss = compat.shard_map(
+                per_client,
+                mesh=mesh,
+                in_specs=(jax.tree.map(lambda _: P(), state.params),
+                          jax.tree.map(
+                              lambda l: P(dp, *([None] * (l.ndim - 1))),
+                              batch)),
+                out_specs=(jax.tree.map(
+                    lambda l: P(dp, *([None] * l.ndim)), state.params), P()),
+                axis_names=set(dp),
+            )(state.params, batch)
 
         # phase 2 — ring aggregation (manual over every axis; the in_specs
         # reshard grads to their param-aligned shardings, which is also the
         # model-axis grad all-reduce for model-replicated leaves)
-        params_in = state.params
-        prev_in = state.tcs_prev if needs_tcs else state.params
-        stats_specs = jax.tree.map(lambda _: P(),
-                                   ring_mod.RingStats(0., 0., 0.))
-        stage_ef_new = state.stage_ef
-        relay_bits = None
-        if nested_plan is None:
-            agg_flat, ef_new, stats = compat.shard_map(
-                ring_fn,
-                mesh=mesh,
-                in_specs=(layout.grads_in_specs(dp), P(dp, "model"), P(dp),
-                          P(dp), layout.param_in_specs(),
-                          layout.param_in_specs()),
-                out_specs=(fs, P(dp, "model"), stats_specs),
-                axis_names=manual_axes,
-            )(grads_stacked, state.ef, weights, participate, params_in,
-              prev_in)
-        else:
-            se_specs = tuple(P(dp, "model") for _ in state.stage_ef)
-            agg_flat, ef_new, stage_ef_new, stats, relay_bits = \
-                compat.shard_map(
-                    nested_ring_fn,
+        with jax.named_scope(scopes.AGGREGATE):
+            params_in = state.params
+            prev_in = state.tcs_prev if needs_tcs else state.params
+            stats_specs = jax.tree.map(lambda _: P(),
+                                       ring_mod.RingStats(0., 0., 0.))
+            stage_ef_new = state.stage_ef
+            relay_bits = None
+            if nested_plan is None:
+                agg_flat, ef_new, stats = compat.shard_map(
+                    ring_fn,
                     mesh=mesh,
-                    in_specs=(layout.grads_in_specs(dp), P(dp, "model"),
-                              se_specs, P(dp), P(dp),
-                              layout.param_in_specs(),
+                    in_specs=(layout.grads_in_specs(dp), P(dp, "model"), P(dp),
+                              P(dp), layout.param_in_specs(),
                               layout.param_in_specs()),
-                    out_specs=(fs, P(dp, "model"), se_specs, stats_specs,
-                               P()),
+                    out_specs=(fs, P(dp, "model"), stats_specs),
                     axis_names=manual_axes,
-                )(grads_stacked, state.ef, state.stage_ef, weights,
-                  participate, params_in, prev_in)
+                )(grads_stacked, state.ef, weights, participate, params_in,
+                  prev_in)
+            else:
+                se_specs = tuple(P(dp, "model") for _ in state.stage_ef)
+                agg_flat, ef_new, stage_ef_new, stats, relay_bits = \
+                    compat.shard_map(
+                        nested_ring_fn,
+                        mesh=mesh,
+                        in_specs=(layout.grads_in_specs(dp), P(dp, "model"),
+                                  se_specs, P(dp), P(dp),
+                                  layout.param_in_specs(),
+                                  layout.param_in_specs()),
+                        out_specs=(fs, P(dp, "model"), se_specs, stats_specs,
+                                   P()),
+                        axis_names=manual_axes,
+                    )(grads_stacked, state.ef, state.stage_ef, weights,
+                      participate, params_in, prev_in)
 
         # phase 3 — ZeRO flat optimizer
-        total_w = jnp.maximum(jnp.sum(weights * participate), 1e-9)
-        grad_est = agg_flat.astype(jnp.float32) / total_w
-        lr_scale = lr_schedule(state.step, warmup=tc.lr_warmup,
-                               decay_steps=tc.lr_decay_steps)
-        master_new, opt_new = opt_mod.apply_flat(
-            tc.opt, state.opt, state.master, grad_est, lr_scale)
-        master_new = jax.lax.with_sharding_constraint(
-            master_new, NamedSharding(mesh, fs))
+        with jax.named_scope(scopes.OPTIMIZER):
+            total_w = jnp.maximum(jnp.sum(weights * participate), 1e-9)
+            grad_est = agg_flat.astype(jnp.float32) / total_w
+            lr_scale = lr_schedule(state.step, warmup=tc.lr_warmup,
+                                   decay_steps=tc.lr_decay_steps)
+            master_new, opt_new = opt_mod.apply_flat(
+                tc.opt, state.opt, state.master, grad_est, lr_scale)
+            master_new = jax.lax.with_sharding_constraint(
+                master_new, NamedSharding(mesh, fs))
 
-        # downlink — w^{t+1} broadcast
-        params_new = compat.shard_map(
-            downlink_fn, mesh=mesh, in_specs=(fs,),
-            out_specs=layout.param_out_specs(), axis_names=manual_axes,
-        )(master_new)
+            # downlink — w^{t+1} broadcast
+            params_new = compat.shard_map(
+                downlink_fn, mesh=mesh, in_specs=(fs,),
+                out_specs=layout.param_out_specs(), axis_names=manual_axes,
+            )(master_new)
 
-        tcs_prev_new = state.tcs_prev
-        if needs_tcs:
-            tcs_prev_new = jax.tree.map(
-                lambda p: p.astype(jnp.dtype(tc.agg_dtype)), state.params)
+            tcs_prev_new = state.tcs_prev
+            if needs_tcs:
+                tcs_prev_new = jax.tree.map(
+                    lambda p: p.astype(jnp.dtype(tc.agg_dtype)), state.params)
+            step_new = state.step + 1
 
         metrics = {
             "loss": loss,
@@ -566,14 +572,15 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh,
         if relay_bits is not None:
             # the scarce-link tier (pod-seam DCI / inter-cluster relay)
             metrics["agg_bits_relay"] = relay_bits
-        if telemetry:
-            from repro.runtime.fault import dead_banked_mass
-            metrics["ef_mass"] = (
-                jnp.sum(jnp.abs(ef_new))
-                + sum(jnp.sum(jnp.abs(se)) for se in stage_ef_new or ()))
-            metrics["ef_dead_mass"] = dead_banked_mass(
-                ef_new.reshape(k_dp, -1), participate)
-        new_state = TrainState(step=state.step + 1, params=params_new,
+        with jax.named_scope(scopes.AGGREGATE):
+            if telemetry:
+                from repro.runtime.fault import dead_banked_mass
+                metrics["ef_mass"] = (
+                    jnp.sum(jnp.abs(ef_new))
+                    + sum(jnp.sum(jnp.abs(se)) for se in stage_ef_new or ()))
+                metrics["ef_dead_mass"] = dead_banked_mass(
+                    ef_new.reshape(k_dp, -1), participate)
+        new_state = TrainState(step=step_new, params=params_new,
                                master=master_new, opt=opt_new, ef=ef_new,
                                tcs_prev=tcs_prev_new, stage_ef=stage_ef_new)
         return new_state, metrics
@@ -623,66 +630,70 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh,
         # cohort (the model axis stays auto inside, which XLA only supports
         # without a vmapped batch dim; grads are embarrassingly parallel so
         # looping loses nothing — phase 2 is where cohorts share the wire)
-        g_list, l_list = [], []
-        for i in range(b_coh):
-            params_i = jax.tree.map(lambda p: p[i], state.params)
-            batch_i = jax.tree.map(lambda x: x[i], batch)
-            g_i, l_i = compat.shard_map(
-                per_client,
-                mesh=mesh,
-                in_specs=(jax.tree.map(lambda _: P(), params_i),
-                          jax.tree.map(
-                              lambda l: P(dp, *([None] * (l.ndim - 1))),
-                              batch_i)),
-                out_specs=(jax.tree.map(
-                    lambda l: P(dp, *([None] * l.ndim)), params_i), P()),
-                axis_names=set(dp),
-            )(params_i, batch_i)
-            g_list.append(g_i)
-            l_list.append(l_i)
-        grads_stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *g_list)
-        loss = jnp.stack(l_list)
+        with jax.named_scope(scopes.GRADS):
+            g_list, l_list = [], []
+            for i in range(b_coh):
+                params_i = jax.tree.map(lambda p: p[i], state.params)
+                batch_i = jax.tree.map(lambda x: x[i], batch)
+                g_i, l_i = compat.shard_map(
+                    per_client,
+                    mesh=mesh,
+                    in_specs=(jax.tree.map(lambda _: P(), params_i),
+                              jax.tree.map(
+                                  lambda l: P(dp, *([None] * (l.ndim - 1))),
+                                  batch_i)),
+                    out_specs=(jax.tree.map(
+                        lambda l: P(dp, *([None] * l.ndim)), params_i), P()),
+                    axis_names=set(dp),
+                )(params_i, batch_i)
+                g_list.append(g_i)
+                l_list.append(l_i)
+            grads_stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *g_list)
+            loss = jnp.stack(l_list)
 
         # phase 2 — batched ring aggregation: B cohorts, one wavefront
-        params_in = state.params
-        prev_in = state.tcs_prev if needs_tcs else state.params
-        stats_specs = jax.tree.map(lambda _: P(),
-                                   ring_mod.RingStats(0., 0., 0.))
-        agg_flat, ef_new, stats = compat.shard_map(
-            ring_fn_b,
-            mesh=mesh,
-            in_specs=(_coh_specs(layout.grads_in_specs(dp)),
-                      P(None, dp, "model"), P(None, dp), P(None, dp),
-                      _coh_specs(layout.param_in_specs()),
-                      _coh_specs(layout.param_in_specs())),
-            out_specs=(fs_b, P(None, dp, "model"), stats_specs),
-            axis_names=manual_axes,
-        )(grads_stacked, state.ef, weights, participate, params_in,
-          prev_in)
+        with jax.named_scope(scopes.AGGREGATE):
+            params_in = state.params
+            prev_in = state.tcs_prev if needs_tcs else state.params
+            stats_specs = jax.tree.map(lambda _: P(),
+                                       ring_mod.RingStats(0., 0., 0.))
+            agg_flat, ef_new, stats = compat.shard_map(
+                ring_fn_b,
+                mesh=mesh,
+                in_specs=(_coh_specs(layout.grads_in_specs(dp)),
+                          P(None, dp, "model"), P(None, dp), P(None, dp),
+                          _coh_specs(layout.param_in_specs()),
+                          _coh_specs(layout.param_in_specs())),
+                out_specs=(fs_b, P(None, dp, "model"), stats_specs),
+                axis_names=manual_axes,
+            )(grads_stacked, state.ef, weights, participate, params_in,
+              prev_in)
 
         # phase 3 — ZeRO flat optimizer, vmapped per cohort
-        total_w = jnp.maximum(jnp.sum(weights * participate, axis=-1),
-                              1e-9)
-        grad_est = agg_flat.astype(jnp.float32) / total_w[:, None]
-        lr_scale = lr_schedule(state.step, warmup=tc.lr_warmup,
-                               decay_steps=tc.lr_decay_steps)
-        master_new, opt_new = jax.vmap(
-            lambda o, ms, gr, ls: opt_mod.apply_flat(tc.opt, o, ms, gr,
-                                                     ls))(
-            state.opt, state.master, grad_est, lr_scale)
-        master_new = jax.lax.with_sharding_constraint(
-            master_new, NamedSharding(mesh, fs_b))
+        with jax.named_scope(scopes.OPTIMIZER):
+            total_w = jnp.maximum(jnp.sum(weights * participate, axis=-1),
+                                  1e-9)
+            grad_est = agg_flat.astype(jnp.float32) / total_w[:, None]
+            lr_scale = lr_schedule(state.step, warmup=tc.lr_warmup,
+                                   decay_steps=tc.lr_decay_steps)
+            master_new, opt_new = jax.vmap(
+                lambda o, ms, gr, ls: opt_mod.apply_flat(tc.opt, o, ms, gr,
+                                                         ls))(
+                state.opt, state.master, grad_est, lr_scale)
+            master_new = jax.lax.with_sharding_constraint(
+                master_new, NamedSharding(mesh, fs_b))
 
-        params_new = compat.shard_map(
-            downlink_fn_b, mesh=mesh, in_specs=(fs_b,),
-            out_specs=_coh_specs(layout.param_out_specs()),
-            axis_names=manual_axes,
-        )(master_new)
+            params_new = compat.shard_map(
+                downlink_fn_b, mesh=mesh, in_specs=(fs_b,),
+                out_specs=_coh_specs(layout.param_out_specs()),
+                axis_names=manual_axes,
+            )(master_new)
 
-        tcs_prev_new = state.tcs_prev
-        if needs_tcs:
-            tcs_prev_new = jax.tree.map(
-                lambda p: p.astype(jnp.dtype(tc.agg_dtype)), state.params)
+            tcs_prev_new = state.tcs_prev
+            if needs_tcs:
+                tcs_prev_new = jax.tree.map(
+                    lambda p: p.astype(jnp.dtype(tc.agg_dtype)), state.params)
+            step_new = state.step + 1
 
         metrics = {
             "loss": loss,
@@ -691,12 +702,13 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh,
             "agg_err_sq": stats.err_sq,
             "lr_scale": lr_scale,
         }
-        if telemetry:
-            from repro.runtime.fault import dead_banked_mass
-            metrics["ef_mass"] = jnp.sum(jnp.abs(ef_new), axis=(1, 2))
-            metrics["ef_dead_mass"] = jax.vmap(dead_banked_mass)(
-                ef_new.reshape(b_coh, k_dp, -1), participate)
-        new_state = TrainState(step=state.step + 1, params=params_new,
+        with jax.named_scope(scopes.AGGREGATE):
+            if telemetry:
+                from repro.runtime.fault import dead_banked_mass
+                metrics["ef_mass"] = jnp.sum(jnp.abs(ef_new), axis=(1, 2))
+                metrics["ef_dead_mass"] = jax.vmap(dead_banked_mass)(
+                    ef_new.reshape(b_coh, k_dp, -1), participate)
+        new_state = TrainState(step=step_new, params=params_new,
                                master=master_new, opt=opt_new, ef=ef_new,
                                tcs_prev=tcs_prev_new,
                                stage_ef=state.stage_ef)

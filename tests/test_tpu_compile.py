@@ -26,6 +26,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.agg import compile_plan, execute
 from repro.core.algorithms import AggConfig, AggKind
 from repro.kernels import level
+from repro.obs import scopes
 from repro.topo.graph import walker_delta
 
 
@@ -137,3 +138,53 @@ def test_unfused_round_has_no_kernels(one_chip):
     text = _compile(lambda g, e, w: execute(cfg, plan, g, e, w),
                     spec((k, d)), spec((k, d)), spec((k,)))
     assert "tpu_custom_call" not in text
+
+
+# ---------------------------------------------------------------------------
+# Layer scopes of the fused round (repro.obs.scopes)
+# ---------------------------------------------------------------------------
+
+SCOPED_D = 50_000       # not a whole number of kernel blocks: the operands pad
+
+
+@pytest.fixture(scope="module")
+def scoped_round(one_chip):
+    """The working instructions of a K=4 CL-SIA threshold chain round with
+    the kernels on, each with its scope."""
+    import repro.kernels.ops as kops
+    k, d = 4, SCOPED_D
+    cfg = AggConfig(kind=AggKind.CL_SIA, q=d // 100, topq_impl="threshold")
+    spec = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
+                             sharding=one_chip)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(kops, "resolve", lambda mode: (True, False))
+        text = _compile(
+            lambda g, e, w, p: execute(cfg, compile_plan(k), g, e, w,
+                                       participate=p),
+            spec((k, d)), spec((k, d)), spec((k,)), spec((k,)))
+    return [i for i in scopes.instructions(text)
+            if i.executed and i.opcode != "parameter"]
+
+
+@pytest.mark.parametrize("kernel,scope", [
+    ("cl_fuse_level_pallas", scopes.NODE_STEP),
+    ("count_ge_fused_level_pallas", scopes.TAU_SEARCH),
+])
+def test_kernels_keep_their_names_in_their_layer(scoped_round, kernel,
+                                                 scope):
+    calls = [i for i in scoped_round if i.name.rsplit(".", 1)[0] == kernel]
+    assert calls and all(i.opcode == "custom-call" for i in calls)
+    assert all(i.scope == scope and not i.inherited for i in calls)
+
+
+def test_operand_pads_are_kernel_layout(scoped_round):
+    n_blocks, pad = level._geometry(SCOPED_D)
+    assert pad > 0
+    padded = f"f32[{n_blocks * level.BLOCK}]"     # one lane: W = 1
+    pads = [i for i in scoped_round
+            if "pad" in i.name and i.shape.startswith(padded)]
+    # g, e and γ_in into each of the three count passes and into cl_fuse
+    assert len(pads) == 6
+    assert all(i.scope == scopes.KERNEL_LAYOUT and not i.inherited
+               for i in pads)
+    assert not any(i.scope is None for i in scoped_round)
