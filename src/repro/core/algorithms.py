@@ -336,7 +336,8 @@ def _lane_sparsifier_state(cfg: AggConfig, operand: sp.TauOperand, q: int,
     reproduces the unfused ``_topq_local`` keep set lane by lane:
 
     * dynamic budgets → the sort-threshold keep mask, τ = +inf;
-    * exact Top-Q     → the ``lax.top_k`` support mask, τ = +inf;
+    * exact Top-Q     → the ``lax.top_k`` support mask (by the counting
+      select at large d, :func:`repro.core.sparsify.topq_mask`), τ = +inf;
     * threshold Top-Q → mask None, τ from the batched branch-and-bisect
       over the *unmaterialized* operand (fused-operand count kernels; one
       histogram pass under ``cfg.tau_impl="hist"``).
@@ -355,8 +356,7 @@ def _lane_sparsifier_state(cfg: AggConfig, operand: sp.TauOperand, q: int,
             None, q, branch=cfg.hist_branch, rounds=cfg.hist_rounds,
             operand_fn=operand, tau_impl=cfg.tau_impl)
         return None, jnp.where(p > 0, tau, jnp.inf)
-    x = operand.materialize()
-    mask = jax.vmap(lambda row: sp.topq_mask(row, q))(x)
+    mask = sp.topq_mask(operand.materialize(), q)
     return mask * p[:, None], _lane_inf(w)
 
 

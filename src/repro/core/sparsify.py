@@ -7,8 +7,9 @@ operates on flat 1-D vectors; pytree plumbing lives in :mod:`repro.core.api`.
 
 Two implementations are provided:
 
-* exact: ``jax.lax.top_k`` based — the oracle used by the simulator, tests
-  and small models;
+* exact: the ``jax.lax.top_k`` support (by ``top_k`` on short vectors, by
+  a counting select on long ones) — the oracle used by the simulator,
+  tests and the train step;
 * threshold: histogram + bisection (distributable; composes with sharding via
   a single ``psum`` of the histogram) — the production path, with the
   perf-critical histogram implemented as a Pallas kernel in
@@ -17,6 +18,7 @@ Two implementations are provided:
 
 from __future__ import annotations
 
+import collections
 import functools
 import math
 from typing import Callable, NamedTuple, Optional, Tuple
@@ -32,23 +34,110 @@ Array = jax.Array
 # ---------------------------------------------------------------------------
 # Exact Top-Q (oracle)
 # ---------------------------------------------------------------------------
+#
+# Two lowerings of one selection: the Q largest magnitudes, ties at the
+# Q-th broken toward the lower index (``lax.top_k``'s documented order).
+# At large k the TPU lowers ``top_k`` to a full sort of the vector; the
+# counting select finds the same support with streaming compare-and-count
+# passes and no sort or scatter. Both give bitwise the same mask, so the
+# choice is speed alone, made from the shape.
+
+#: Vectors of at least this many entries take the counting select, shorter
+#: ones ``lax.top_k``. On a TPU v5e the two cost the same at 2^16 entries
+#: (Top-1 %), the select wins from 2^18 on (PERF.md §6).
+_SELECT_MIN_D = 1 << 16
+#: Bits resolved per counting pass (1, 2, 4 or 8): each pass counts
+#: 2**bits - 1 candidates at once. On a TPU v5e at 129 M entries 2 bits
+#: beat 1 and 4 (PERF.md §6).
+_SELECT_DIGIT_BITS = 2
+
+#: Trace-time record of the exact selections lowered: ``(path, W, d, q)``
+#: → times traced, path ``"select"`` or ``"top_k"`` (W = 1 for a vector).
+EXACT_PATHS: collections.Counter = collections.Counter()
+
+
+def _topk_keep(x: Array, q: int) -> Array:
+    _, idx = jax.lax.top_k(jnp.abs(x), q)
+    return jnp.zeros(x.shape, bool).at[idx].set(True)
+
+
+def _largest_passing(count, ok, nbits: int, batch: tuple) -> Array:
+    """Largest ``v`` in ``[0, 2**nbits)`` with ``ok(count(v))``, uint32.
+
+    ``count(c [...])`` gives a count per lane and ``ok`` holds
+    for ``v = 0`` and fails from some ``v`` on (monotone), so each pass
+    fixes one digit: it counts every nonzero value of the digit above the
+    bits found so far, all in one streaming pass, and keeps the largest
+    that passes. The digit width divides 32, so no candidate wraps.
+    """
+    b = _SELECT_DIGIT_BITS
+    steps = -(-nbits // b)
+
+    def body(s, v):
+        shift = ((steps - 1 - s) * b).astype(jnp.uint32)
+        # one reduction per candidate: XLA fuses them into one multi-output
+        # pass over the vector (a [R, d] broadcast would not be)
+        n = sum(ok(count(v + (jnp.uint32(j) << shift))).astype(jnp.uint32)
+                for j in range(1, 1 << b))
+        return v + (n << shift)
+
+    return jax.lax.fori_loop(0, steps, body, jnp.zeros(batch, jnp.uint32))
+
+
+def _select_keep(x: Array, q: int) -> Array:
+    """The ``lax.top_k`` support of ``x`` ([..., d], 0 < q < d) by counting.
+
+    ``key`` is the bit pattern of |x| as float32, monotone in |x|. One
+    digit search finds the q-th largest key ``k`` (the largest with
+    ``#{key >= k} >= q``); the ``r = q - #{key > k}`` ties at it are kept
+    lowest index first, cut at the largest ``i`` with ``#{j < i : key_j =
+    k} < r``, which a second search over the index bits finds.
+    """
+    batch, d = x.shape[:-1], x.shape[-1]
+    key = jax.lax.bitcast_convert_type(jnp.abs(x.astype(jnp.float32)),
+                                       jnp.uint32)
+    kth = _largest_passing(
+        lambda c: jnp.sum(key >= c[..., None], axis=-1, dtype=jnp.int32),
+        lambda n: n >= q, 31, batch)[..., None]
+    tie = key == kth
+    r = q - jnp.sum(key > kth, axis=-1, dtype=jnp.int32)
+    idx = jax.lax.iota(jnp.uint32, d)
+    cut = _largest_passing(
+        lambda c: jnp.sum(tie & (idx < c[..., None]), axis=-1,
+                          dtype=jnp.int32),
+        lambda n: n < r, max(1, (d - 1).bit_length()), batch)
+    return (key > kth) | (tie & (idx <= cut[..., None]))
+
+
+def _exact_keep(x: Array, q: int) -> Array:
+    """Boolean Top-Q support of ``x`` ([d] or [W, d]) for 0 < q < d."""
+    d = x.shape[-1]
+    w = x.shape[0] if x.ndim == 2 else 1
+    select = (d >= _SELECT_MIN_D and jnp.issubdtype(x.dtype, jnp.floating)
+              and jnp.dtype(x.dtype).itemsize <= 4)
+    EXACT_PATHS["select" if select else "top_k", w, d, q] += 1
+    if x.ndim == 1:
+        return (_select_keep if select else _topk_keep)(x, q)
+    if not select:
+        return jax.vmap(_topk_keep, (0, None))(x, q)
+    if w == 1:      # a [1, d] array tiles one row per TPU vreg: count it flat
+        return _select_keep(x[0], q)[None]
+    return _select_keep(x, q)
+
 
 @jax.named_scope(scopes.TAU_SEARCH)
 def topq(x: Array, q: int) -> Array:
     """``S(x, Q)``: keep the Q largest-magnitude entries of ``x``, zero the rest.
 
-    Ties are broken arbitrarily but deterministically (lax.top_k order).
-    ``q`` must be a static Python int (shapes are static under jit).
+    ``x`` is [d] or [W, d] (one selection per lane). Ties at the Q-th
+    magnitude keep the lower indices first (``lax.top_k`` order). ``q``
+    must be a static Python int (shapes are static under jit).
     """
     if q <= 0:
         return jnp.zeros_like(x)
-    d = x.shape[-1]
-    if q >= d:
+    if q >= x.shape[-1]:
         return x
-    mag = jnp.abs(x)
-    _, idx = jax.lax.top_k(mag, q)
-    mask = jnp.zeros_like(x, dtype=bool).at[idx].set(True)
-    return jnp.where(mask, x, 0)
+    return jnp.where(_exact_keep(x, q), x, 0)
 
 
 @jax.named_scope(scopes.TAU_SEARCH)
@@ -56,12 +145,9 @@ def topq_mask(x: Array, q: int) -> Array:
     """``s(x, Q)``: the 0/1 float mask of the Top-Q support of ``x``."""
     if q <= 0:
         return jnp.zeros_like(x)
-    d = x.shape[-1]
-    if q >= d:
+    if q >= x.shape[-1]:
         return jnp.ones_like(x)
-    mag = jnp.abs(x)
-    _, idx = jax.lax.top_k(mag, q)
-    return jnp.zeros_like(x).at[idx].set(1.0)
+    return _exact_keep(x, q).astype(x.dtype)
 
 
 def support(x: Array) -> Array:

@@ -27,6 +27,7 @@ d-term float reduction whose accumulation order XLA picks per compiled
 graph, so even two unfused graphs are not guaranteed the same last bit.
 """
 
+import collections
 import dataclasses
 import functools
 
@@ -38,8 +39,9 @@ import pytest
 from repro.agg import compile_plan, execute
 from repro.agg.device import _use_compact
 from repro.core import sparsify as sp
-from repro.core.algorithms import (AggConfig, AggKind, NodeCtx, index_bits,
-                                   fused_node_steps, level_step, node_step)
+from repro.core.algorithms import (AggConfig, AggKind, HopStats, NodeCtx,
+                                   index_bits, fused_node_steps, level_step,
+                                   node_step)
 from repro.core.chain import run_chain
 from repro.topo.tree import AggTree, PS
 
@@ -304,3 +306,113 @@ def test_batched_threshold_matches_vmapped_scalar():
                                       np.asarray(scalar))
         kept = jnp.sum(jnp.abs(x) >= batched[:, None], axis=-1)
         assert (np.asarray(kept) >= q).all()
+
+
+# ---------------------------------------------------------------------------
+# Exact Top-Q by the counting select: bitwise the lax.top_k path
+# ---------------------------------------------------------------------------
+
+_NEVER_SELECT = 1 << 62
+
+
+def _tied_level(w_lanes, d, seed):
+    """Integer-valued g and half-integer e: heavy ties at every q-th value."""
+    kg, ke = jax.random.split(jax.random.PRNGKey(seed))
+    g = jax.random.randint(kg, (w_lanes, d), -4, 5).astype(jnp.float32)
+    e = 0.5 * jax.random.randint(ke, (w_lanes, d), -1, 2).astype(jnp.float32)
+    return g, e
+
+
+@pytest.mark.parametrize("w_lanes", [1, 3])
+@pytest.mark.parametrize("kind", [AggKind.CL_SIA, AggKind.CL_TC_SIA])
+def test_fused_exact_level_select_matches_top_k(kind, w_lanes, monkeypatch):
+    """Above the gate the fused exact level selects by counting; its
+    aggregate, EF and HopStats equal the ``lax.top_k`` path's bitwise."""
+    d = sp._SELECT_MIN_D + 37
+    cfg = AggConfig(kind=kind, q=d // 100, topq_impl="exact",
+                    kernel_mode="always")
+    g, e = _tied_level(w_lanes, d, seed=w_lanes)
+    gin = jnp.round(jnp.roll(g, 1, axis=0) * 0.5)
+    w = jnp.ones((w_lanes,), jnp.float32)
+    p = jnp.ones((w_lanes,), jnp.float32)
+    gm = (jnp.zeros((d,)).at[::97].set(1.0) if kind == AggKind.CL_TC_SIA
+          else jnp.zeros((d,)))
+
+    def run():
+        before = collections.Counter(sp.EXACT_PATHS)
+        out = jax.jit(lambda *a: level_step(cfg)(*a))(g, gin, e, w, p, gm)
+        got = collections.Counter(sp.EXACT_PATHS)
+        got.subtract(before)
+        return out, +got
+
+    q = cfg.q if kind == AggKind.CL_SIA else cfg.q_local
+    new, paths = run()
+    assert paths == collections.Counter({("select", w_lanes, d, q): 1})
+    monkeypatch.setattr(sp, "_SELECT_MIN_D", _NEVER_SELECT)
+    old, paths = run()
+    assert paths == collections.Counter({("top_k", w_lanes, d, q): 1})
+    for a, b, name in [(new[0], old[0], "gout"), (new[1], old[1], "e_new")]:
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+    for field in HopStats._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(new[2], field)),
+                                      np.asarray(getattr(old[2], field)),
+                                      err_msg=field)
+    assert int(jnp.sum(new[2].nnz_local)) > 0
+
+
+def _tiny_train():
+    from repro.agg.device import ring_chain_plan
+    from repro.configs.base import ModelConfig
+    from repro.launch.mesh import make_mesh
+    from repro.optim.optimizers import OptConfig
+    from repro.train.state import TrainConfig
+    from repro.train.step import build_train_step, init_state
+    mesh = make_mesh((1, 1), ("data", "model"))
+    cfg = ModelConfig(name="tiny", family="ssm", num_layers=2, d_model=32,
+                      num_heads=0, num_kv_heads=0, d_ff=0, vocab_size=64,
+                      ssm_state=16, ssm_headdim=16, ssm_expand=2,
+                      ssm_chunk=16, ssm_conv=4, param_dtype="float32")
+    tc = TrainConfig(agg=AggConfig(kind=AggKind.CL_SIA, q=1,
+                                   topq_impl="exact"),
+                     opt=OptConfig(name="adamw", lr=1e-3), q_frac=0.05,
+                     agg_dtype="bfloat16", ef_dtype="bfloat16")
+    plan = ring_chain_plan(1)
+    toks = jax.random.randint(jax.random.PRNGKey(3), (2, 33), 0, 64)
+    batch = {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
+    with jax.set_mesh(mesh):
+        state = init_state(cfg, tc, mesh, jax.random.PRNGKey(0),
+                           topology=plan)
+
+    def step():
+        with jax.set_mesh(mesh):
+            fn = jax.jit(build_train_step(cfg, tc, mesh, topology=plan))
+            return (fn(state, batch),
+                    fn.lower(state, batch).compile().as_text())
+    return step
+
+
+def test_train_step_select_matches_top_k(monkeypatch):
+    """One CPU train step of a tiny model: with the gate lowered the exact
+    CL-SIA hop selects by counting, the new state and metrics equal the
+    ``lax.top_k`` step's bitwise, and no top-k, sort or scatter is left in
+    the aggregation's compiled program."""
+    from repro.obs import scopes
+    step = _tiny_train()
+    monkeypatch.setattr(sp, "_SELECT_MIN_D", 1)
+    before = collections.Counter(sp.EXACT_PATHS)
+    new, text = step()
+    paths = collections.Counter(sp.EXACT_PATHS)
+    paths.subtract(before)
+    assert {k[0] for k in +paths} == {"select"}
+    agg = [i for i in scopes.instructions(text)
+           if scopes.AGGREGATE in i.path]
+    assert agg
+    assert not [i for i in agg if i.opcode in ("sort", "scatter")
+                or "top_k" in i.op_name or "topk" in i.opcode.lower()]
+    assert "sort(" not in text and "TopK" not in text
+    monkeypatch.setattr(sp, "_SELECT_MIN_D", _NEVER_SELECT)
+    old, text_old = step()
+    assert "top_k" in text_old
+    for a, b in zip(jax.tree.leaves(new), jax.tree.leaves(old)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

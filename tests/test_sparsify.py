@@ -1,5 +1,7 @@
 """Unit + property tests for Top-Q primitives."""
 
+import collections
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -90,3 +92,99 @@ def test_mask_union_and_support():
     np.testing.assert_allclose(np.asarray(sp.mask_union(a, b)), [1, 1, 1, 0])
     np.testing.assert_allclose(
         np.asarray(sp.support(jnp.asarray([0.0, -2.0, 3.0]))), [0, 1, 1])
+
+
+# ---------------------------------------------------------------------------
+# Exact Top-Q by the counting select: bitwise the lax.top_k support
+# ---------------------------------------------------------------------------
+
+def _top_k_keep(x, q):
+    """The ``lax.top_k`` support of a vector: ties lowest index first."""
+    _, idx = jax.lax.top_k(jnp.abs(x), q)
+    return jnp.zeros(x.shape, bool).at[idx].set(True)
+
+
+def _lanes(kind, d=300):
+    """[3, d] float32 lanes of one kind, each with its own tie structure."""
+    rng = np.random.default_rng(sum(map(ord, kind)))
+    if kind == "integer_ties":
+        lanes = [rng.integers(-3, 4, d), rng.integers(-1, 2, d),
+                 np.repeat(rng.integers(-5, 6, d // 10 + 1), 10)[:d]]
+    elif kind == "bf16_quantized":
+        lanes = [np.asarray(jnp.asarray(rng.standard_normal(d) * s,
+                                        jnp.bfloat16).astype(jnp.float32))
+                 for s in (1.0, 1e-3, 3e4)]
+    elif kind == "signed_zeros":
+        z = np.where(rng.random(d) < 0.5, 0.0, -0.0)
+        one = z.copy()
+        one[rng.integers(0, d, 5)] = -2.0
+        lanes = [z, -z, one]
+    elif kind == "inf":
+        a = rng.integers(-2, 3, d).astype(np.float64)
+        a[rng.random(d) < 0.1] = np.inf
+        a[rng.random(d) < 0.05] = -np.inf
+        lanes = [a, np.full(d, -np.inf), np.where(a == 0, np.inf, a)]
+    else:  # "distinct"
+        lanes = [rng.standard_normal(d) * s for s in (1.0, 1e-30, 1e-42)]
+    return jnp.asarray(np.stack(lanes), jnp.float32)
+
+
+@pytest.mark.parametrize("q_of_d", [lambda d: 0, lambda d: 1,
+                                    lambda d: d // 3, lambda d: d - 1,
+                                    lambda d: d, lambda d: d + 1],
+                         ids=["0", "1", "third", "d-1", "d", "d+1"])
+@pytest.mark.parametrize("kind", ["integer_ties", "bf16_quantized",
+                                  "signed_zeros", "inf", "distinct"])
+def test_counting_select_is_the_top_k_support(kind, q_of_d, monkeypatch):
+    """With the gate at 1 every vector takes the counting select; its mask
+    and ``topq`` output equal the ``lax.top_k`` ones bitwise: per vector,
+    on [W, d] lanes at once, and vmapped, all under jit."""
+    monkeypatch.setattr(sp, "_SELECT_MIN_D", 1)
+    x = _lanes(kind)
+    d = x.shape[-1]
+    q = q_of_d(d)
+    if q <= 0:
+        want = jnp.zeros(x.shape, bool)
+    elif q >= d:
+        want = jnp.ones(x.shape, bool)
+    else:
+        want = jax.vmap(_top_k_keep, (0, None))(x, q)
+    want_mask = np.asarray(want.astype(x.dtype))
+    want_vals = np.asarray(jnp.where(want, x, 0))
+
+    before = collections.Counter(sp.EXACT_PATHS)
+    # fresh functions, so no trace cached under another gate is reused
+    mask = jax.jit(lambda v: sp.topq_mask(v, q))
+    np.testing.assert_array_equal(np.asarray(mask(x)), want_mask)
+    np.testing.assert_array_equal(
+        np.stack([np.asarray(mask(row)) for row in x]), want_mask)
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(jax.vmap(lambda r: sp.topq_mask(r, q)))(x)),
+        want_mask)
+    vals = np.asarray(jax.jit(lambda v: sp.topq(v, q))(x))
+    assert vals.tobytes() == want_vals.tobytes()      # -0.0 kept as -0.0
+    if 0 < q < d:
+        assert (want_mask.sum(axis=-1) == q).all()
+        got = collections.Counter(sp.EXACT_PATHS)
+        got.subtract(before)
+        assert +got == collections.Counter({("select", 3, d, q): 2,
+                                            ("select", 1, d, q): 2})
+
+
+def test_exact_paths_record_the_gate():
+    """The path counter records the counting select at and above the gate
+    and ``lax.top_k`` below it, with (W, d, q), once per trace."""
+    d = sp._SELECT_MIN_D
+    before = collections.Counter(sp.EXACT_PATHS)
+    for shape in [(d - 1,), (d,), (3, d), (1, d)]:
+        jax.make_jaxpr(lambda v: sp.topq_mask(v, 7))(
+            jax.ShapeDtypeStruct(shape, jnp.float32))
+    jax.make_jaxpr(lambda v: sp.topq(v, 5))(
+        jax.ShapeDtypeStruct((2, d - 1), jnp.float32))
+    jax.make_jaxpr(lambda v: sp.topq_mask(v, 0))(        # keeps nothing
+        jax.ShapeDtypeStruct((d,), jnp.float32))
+    got = collections.Counter(sp.EXACT_PATHS)
+    got.subtract(before)
+    assert +got == collections.Counter({
+        ("top_k", 1, d - 1, 7): 1, ("select", 1, d, 7): 2,
+        ("select", 3, d, 7): 1, ("top_k", 2, d - 1, 5): 1})

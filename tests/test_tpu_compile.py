@@ -188,3 +188,19 @@ def test_operand_pads_are_kernel_layout(scoped_round):
     assert all(i.scope == scopes.KERNEL_LAYOUT and not i.inherited
                for i in pads)
     assert not any(i.scope is None for i in scoped_round)
+
+
+@pytest.mark.parametrize("w", [1, 8])
+def test_exact_select_compiles_for_v5e(one_chip, w):
+    """The train cell's exact Top-Q (1 % of all 129,057,216 entries) and a
+    W = 8 level at 2^24 compile to counting passes: no sort, no top-k and
+    no scatter, and a single lane is counted as a flat vector."""
+    from repro.core import sparsify as sp
+    d = 129_057_216 if w == 1 else 1 << 24
+    x = jax.ShapeDtypeStruct((w, d), jnp.float32, sharding=one_chip)
+    text = _compile(lambda v: sp.topq_mask(v, d // 100), x)
+    assert "while" in text
+    for op in (" sort(", "TopK", "top_k", " scatter("):
+        assert op not in text, op
+    if w == 1:      # the keys are counted in the flat [d] tiling
+        assert f"u32[{d}]{{0:T(1024)}}" in text
