@@ -2,8 +2,8 @@
 
 from repro.configs import (codeqwen15_7b, glm4_9b, granite_34b,
                            internvl2_26b, llama4_scout, mamba2_130m,
-                           mixtral_8x7b, musicgen_medium, paper_mnist,
-                           phi4_mini_38b, zamba2_12b)
+                           mixtral_8x7b, musicgen_medium, nemotron3_nano,
+                           paper_mnist, phi4_mini_38b, zamba2_12b)
 from repro.configs.base import SHAPES, ModelConfig, ShapeSpec, shape_cells
 
 _MODULES = {
@@ -17,6 +17,7 @@ _MODULES = {
     "internvl2-26b": internvl2_26b,
     "mamba2-130m": mamba2_130m,
     "musicgen-medium": musicgen_medium,
+    "nemotron3-nano-30b-a3b": nemotron3_nano,
 }
 
 ARCHS = list(_MODULES)

@@ -13,7 +13,7 @@ class ModelConfig:
     """Architecture hyperparameters (one instance per assigned arch)."""
 
     name: str
-    family: str                      # dense | moe | hybrid | vlm | ssm | audio
+    family: str     # dense | moe | hybrid | hybrid_moe | vlm | ssm | audio
     num_layers: int
     d_model: int
     num_heads: int                   # query heads; 0 for attention-free
@@ -27,6 +27,8 @@ class ModelConfig:
     attn_bias: bool = False          # qwen1.5-style QKV bias
     sliding_window: int = 0          # 0 = full attention; >0 = SWA width
 
+    rope: bool = True                # rotary embedding on q and k
+
     # MLP / head variants
     mlp_type: str = "swiglu"         # swiglu (3 mats) | gelu (2 mats)
     tie_embeddings: bool = False     # lm_head = embedᵀ
@@ -35,6 +37,13 @@ class ModelConfig:
     num_experts: int = 0
     num_experts_per_tok: int = 0
     capacity_factor: float = 1.25
+    # dropless expert layer (hybrid_moe): this chip's share of the routed
+    # experts is [experts_first, experts_first + experts_held); the router
+    # still scores all num_experts. 0 = every expert held.
+    experts_held: int = 0
+    experts_first: int = 0
+    routed_scale: float = 1.0        # multiplies the normalised top-k weights
+    shared_d_ff: int = 0             # width of the one shared expert; 0 = none
 
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0
@@ -42,9 +51,15 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_chunk: int = 64
     ssm_conv: int = 4
+    ssm_nheads: int = 0              # 0 → d_inner = ssm_expand · d_model
+    ssm_groups: int = 1              # B/C groups; head h reads h // (H/G)
+    ssm_conv_bias: bool = False
 
     # hybrid (zamba2-style shared attention block cadence)
     attn_every: int = 0              # 0 = no shared block
+    # hybrid_moe: one mixer per pre-norm block, M = Mamba2, E = MoE,
+    # * = attention (Nemotron-H's hybrid_override_pattern)
+    layer_pattern: str = ""
 
     # modality frontend stub
     frontend: str = "none"           # none | vision | audio
@@ -77,6 +92,8 @@ class ModelConfig:
 
     @property
     def d_inner(self) -> int:        # mamba2 inner width
+        if self.ssm_nheads:
+            return self.ssm_nheads * self.ssm_headdim
         return self.ssm_expand * self.d_model
 
     @property
@@ -84,16 +101,45 @@ class ModelConfig:
         return self.d_inner // self.ssm_headdim
 
     @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
     def is_subquadratic(self) -> bool:
         """Eligible for the long_500k cell (DESIGN §4 skip rationale)."""
-        return self.family in ("ssm", "hybrid") or self.sliding_window > 0
+        return (self.family in ("ssm", "hybrid", "hybrid_moe")
+                or self.sliding_window > 0)
 
     @property
     def dtype(self):
         return jnp.dtype(self.param_dtype)
 
+    def _mamba_params(self) -> int:
+        D, di, H = self.d_model, self.d_inner, self.ssm_heads
+        conv_ch = di + 2 * self.ssm_groups * self.ssm_state
+        return (D * (di + conv_ch + H)               # in_proj
+                + self.ssm_conv * conv_ch            # depthwise conv
+                + (conv_ch if self.ssm_conv_bias else 0)
+                + 3 * H + di + di * D)   # A_log, D, dt_bias, norm, out_proj
+
+    def _pattern_param_count(self) -> int:
+        """hybrid_moe: the held experts of each E block are counted."""
+        D, V = self.d_model, self.vocab_size
+        Hq, Hkv, Dh = self.num_heads, self.num_kv_heads, self.resolved_head_dim
+        per = {
+            "M": self._mamba_params(),
+            "E": (D * self.num_experts
+                  + self.held_experts * 2 * D * self.d_ff
+                  + 2 * D * self.shared_d_ff),
+            "*": D * Hq * Dh + 2 * D * Hkv * Dh + Hq * Dh * D,
+        }
+        n = sum(D + per[k] for k in self.layer_pattern)
+        return n + V * D + D + (0 if self.tie_embeddings else D * V)
+
     def param_count(self) -> int:
         """Analytic parameter count (embedding + layers + head)."""
+        if self.layer_pattern:
+            return self._pattern_param_count()
         D, F, V = self.d_model, self.d_ff, self.vocab_size
         Hq, Hkv, Dh = self.num_heads, self.num_kv_heads, self.resolved_head_dim
         n = V * D                                    # embed
@@ -105,10 +151,7 @@ class ModelConfig:
         moe_mlp = self.num_experts * mats * D * F + D * self.num_experts
         ssm = 0
         if self.family in ("ssm", "hybrid"):
-            di, N, H = self.d_inner, self.ssm_state, self.ssm_heads
-            ssm = (D * (2 * di + 2 * N + H)          # in_proj
-                   + self.ssm_conv * (di + 2 * N)    # depthwise conv
-                   + 3 * H + di + di * D)            # A_log, D, dt_bias, norm, out_proj
+            ssm = self._mamba_params()
         per_layer = 2 * D  # norms
         if self.family == "moe":
             per_layer += attn + moe_mlp

@@ -126,7 +126,8 @@ def plain_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
 
 def blocked_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
                       window: int = 0, q_chunk: int = 1024,
-                      k_chunk: int = 1024, q_offset: int = 0) -> Array:
+                      k_chunk: int = 1024, q_offset: int = 0,
+                      remat: bool = False) -> Array:
     """Q-blocked attention: scan over query chunks, each attending to the
     full K/V with materialized [qc × Sk] scores (O(qc·Sk) memory).
 
@@ -136,7 +137,9 @@ def blocked_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
     score-sized all-gathers = 36 TB/step on granite prefill_32k;
     EXPERIMENTS §Perf it.8) — one loop level keeps shardings stable, and
     the true VMEM-tiled flash form belongs in a Pallas kernel, not XLA
-    loops. ``k_chunk`` is accepted for API compatibility.
+    loops. ``k_chunk`` is accepted for API compatibility. ``remat``
+    recomputes each block's scores in the backward pass, so it keeps one
+    block's [qc × Sk] scores live instead of every block's.
     """
     b, sq, hq, dh = q.shape
     sk = k.shape[1]
@@ -164,6 +167,8 @@ def blocked_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
                                   q_offset=q_off)
         return None, out
 
+    if remat:
+        q_block = jax.checkpoint(q_block)
     outs = jax.lax.scan(q_block, None, (jnp.arange(nq), qb))[1]
     return jnp.moveaxis(outs, 0, 1).reshape(b, sq, hq, dh).astype(q.dtype)
 
@@ -198,8 +203,10 @@ def decode_attention(q: Array, k_cache: Array, v_cache: Array, pos: Array,
 # ---------------------------------------------------------------------------
 
 def attn_project_qkv(params, x: Array, *, num_heads: int, num_kv: int,
-                     head_dim: int, rope_theta: float, positions: Array):
-    """x: [B, S, D] → q [B,S,Hq,Dh], k,v [B,S,Hkv,Dh], RoPE applied."""
+                     head_dim: int, rope_theta: float, positions: Array,
+                     rope: bool = True):
+    """x: [B, S, D] → q [B,S,Hq,Dh], k,v [B,S,Hkv,Dh], RoPE applied
+    (unless ``rope`` is off)."""
     b, s, _ = x.shape
     q = jnp.einsum("bsd,dh->bsh", x, params["wq"])
     k = jnp.einsum("bsd,dh->bsh", x, params["wk"])
@@ -211,8 +218,9 @@ def attn_project_qkv(params, x: Array, *, num_heads: int, num_kv: int,
     q = q.reshape(b, s, num_heads, head_dim)
     k = k.reshape(b, s, num_kv, head_dim)
     v = v.reshape(b, s, num_kv, head_dim)
-    q = apply_rope(q, positions, rope_theta)
-    k = apply_rope(k, positions, rope_theta)
+    if rope:
+        q = apply_rope(q, positions, rope_theta)
+        k = apply_rope(k, positions, rope_theta)
     return q, k, v
 
 
@@ -224,7 +232,8 @@ def attn_out(params, o: Array) -> Array:
 def run_attention(params, x: Array, *, cfg_heads: int, cfg_kv: int,
                   head_dim: int, rope_theta: float, window: int,
                   cache=None, pos=None, blocked_threshold: int = 8192,
-                  q_chunk: int = 1024, k_chunk: int = 1024):
+                  q_chunk: int = 1024, k_chunk: int = 1024,
+                  rope: bool = True, remat_blocks: bool = False):
     """Full attention sub-layer.
 
     Modes:
@@ -242,7 +251,7 @@ def run_attention(params, x: Array, *, cfg_heads: int, cfg_kv: int,
         positions = jnp.full((b, s), pos)[..., :]
     q, k, v = attn_project_qkv(
         params, x, num_heads=cfg_heads, num_kv=cfg_kv, head_dim=head_dim,
-        rope_theta=rope_theta, positions=positions)
+        rope_theta=rope_theta, positions=positions, rope=rope)
 
     if cache is not None and pos is not None:
         # decode step. SWA caches are ring buffers of length == window:
@@ -260,7 +269,8 @@ def run_attention(params, x: Array, *, cfg_heads: int, cfg_kv: int,
 
     if s >= blocked_threshold:
         o = blocked_attention(q, k, v, causal=True, window=window,
-                              q_chunk=q_chunk, k_chunk=k_chunk)
+                              q_chunk=q_chunk, k_chunk=k_chunk,
+                              remat=remat_blocks)
     else:
         o = plain_attention(q, k, v, causal=True, window=window)
     new_cache = None

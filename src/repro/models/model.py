@@ -50,33 +50,43 @@ def lm_logits(cfg: ModelConfig, params, h: Array) -> Array:
     return logits
 
 
+def _forward_counted(cfg: ModelConfig, params, tokens: Array,
+                     frontend_embeds=None, frontend_mask=None):
+    """→ (logits [B, S, V], aux, the stack's counters)."""
+    h = embed_inputs(cfg, params, tokens, frontend_embeds, frontend_mask)
+    h, _, aux, counters = transformer.run_stack(cfg, params, h)
+    h = transformer.rms_norm(h, params["final_norm"], cfg.norm_eps)
+    return lm_logits(cfg, params, h), aux, counters
+
+
 def forward(cfg: ModelConfig, params, tokens: Array,
             frontend_embeds=None, frontend_mask=None) -> tuple[Array, Array]:
     """Teacher-forcing forward. tokens [B, S] → (logits [B, S, V], aux)."""
-    h = embed_inputs(cfg, params, tokens, frontend_embeds, frontend_mask)
-    h, _, aux = transformer.run_stack(cfg, params, h)
-    h = transformer.rms_norm(h, params["final_norm"], cfg.norm_eps)
-    return lm_logits(cfg, params, h), aux
+    return _forward_counted(cfg, params, tokens, frontend_embeds,
+                            frontend_mask)[:2]
 
 
 def loss_fn(cfg: ModelConfig, params, batch: dict) -> tuple[Array, dict]:
-    """Mean next-token cross-entropy (f32) + MoE aux. batch: tokens, labels."""
-    logits, aux = forward(cfg, params, batch["tokens"],
-                          batch.get("frontend_embeds"),
-                          batch.get("frontend_mask"))
+    """Mean next-token cross-entropy (f32) + MoE aux. batch: tokens, labels.
+
+    The metrics hold ``counters``, what the model counted (hybrid_moe:
+    ``routed_slots``, ``expert_rows``; empty for every other family)."""
+    logits, aux, counters = _forward_counted(
+        cfg, params, batch["tokens"], batch.get("frontend_embeds"),
+        batch.get("frontend_mask"))
     logits = logits.astype(jnp.float32)
     logp = jax.nn.log_softmax(logits, axis=-1)
     ll = jnp.take_along_axis(logp, batch["labels"][..., None], axis=-1)
     ce = -jnp.mean(ll)
     total = ce + MOE_AUX_WEIGHT * aux
-    return total, {"ce": ce, "aux": aux}
+    return total, {"ce": ce, "aux": aux, "counters": counters}
 
 
 def prefill(cfg: ModelConfig, params, tokens: Array, cache,
             frontend_embeds=None, frontend_mask=None):
     """Process a full prompt, seeding the cache. → (last_logits [B,V], cache)."""
     h = embed_inputs(cfg, params, tokens, frontend_embeds, frontend_mask)
-    h, new_cache, _ = transformer.run_stack(cfg, params, h, cache=cache)
+    h, new_cache, _, _ = transformer.run_stack(cfg, params, h, cache=cache)
     h = transformer.rms_norm(h[:, -1:], params["final_norm"], cfg.norm_eps)
     return lm_logits(cfg, params, h)[:, 0], new_cache
 
@@ -84,7 +94,7 @@ def prefill(cfg: ModelConfig, params, tokens: Array, cache,
 def decode_step(cfg: ModelConfig, params, cache, token: Array, pos: Array):
     """One decode step. token [B] int32, pos scalar → (logits [B,V], cache)."""
     h = params["embed"][token][:, None, :]               # [B, 1, D]
-    h, new_cache, _ = transformer.run_stack(cfg, params, h, cache=cache,
+    h, new_cache, _, _ = transformer.run_stack(cfg, params, h, cache=cache,
                                             pos=pos)
     h = transformer.rms_norm(h, params["final_norm"], cfg.norm_eps)
     return lm_logits(cfg, params, h)[:, 0], new_cache

@@ -78,11 +78,26 @@ def param_pspecs(cfg: ModelConfig, mesh) -> Any:
 
     def mamba_specs():
         # mixed-group in_proj concat dim → replicated over model (DESIGN §5)
-        return {
+        s = {
             "in_proj": P(None, None), "conv_w": P(None, None),
             "dt_bias": P(None), "a_log": P(None), "d_skip": P(None),
             "norm": P(None), "out_proj": P(None, None),
         }
+        if cfg.ssm_conv_bias:
+            s["conv_b"] = P(None)
+        return s
+
+    def expert_share_specs():
+        # expert parallelism: the held experts split over `model`
+        e_ok = _div(cfg.held_experts, m)
+        ex = "model" if e_ok else None
+        s = {"router": P(None, None), "w_up": P(ex, None, None),
+             "w_down": P(ex, None, None)}
+        if cfg.shared_d_ff:
+            f_ok = _div(cfg.shared_d_ff, m)
+            s["shared_up"] = P(None, "model") if f_ok else P(None, None)
+            s["shared_down"] = P("model", None) if f_ok else P(None, None)
+        return s
 
     def stack(spec, extra_lead=1):
         return jax.tree.map(
@@ -97,7 +112,12 @@ def param_pspecs(cfg: ModelConfig, mesh) -> Any:
     if not cfg.tie_embeddings:
         specs["lm_head"] = P(None, "model") if v_ok else P(None, None)
 
-    if cfg.family == "ssm":
+    if cfg.layer_pattern:
+        mixers = {"M": ("mamba", mamba_specs),
+                  "E": ("moe", expert_share_specs), "*": ("attn", attn_specs)}
+        specs["layers"] = [{mixers[k][0]: mixers[k][1](), "ln": P(None)}
+                           for k in cfg.layer_pattern]
+    elif cfg.family == "ssm":
         specs["layers"] = stack({"mamba": mamba_specs(), "ln": P(None)})
     elif cfg.family == "hybrid":
         specs["layers"] = stack({"mamba": mamba_specs(), "ln": P(None)},
@@ -168,6 +188,11 @@ def cache_pspecs(cfg: ModelConfig, mesh, global_batch: int) -> Any:
         pre = [None] * lead
         return P(*pre, dp if b_ok else None, None, None, None)
 
+    if cfg.layer_pattern:
+        kinds = {"M": lambda: {"conv": conv(0), "state": state(0)},
+                 "*": lambda: {"k": attn_kv(0), "v": attn_kv(0)},
+                 "E": lambda: None}
+        return {"layers": [kinds[k]() for k in cfg.layer_pattern]}
     if cfg.family == "ssm":
         return {"layers": {"conv": conv(1), "state": state(1)}}
     if cfg.family == "hybrid":

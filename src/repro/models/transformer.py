@@ -1,11 +1,15 @@
-"""Decoder stacks for all assigned families (dense/moe/ssm/hybrid/vlm/audio).
+"""Decoder stacks for all assigned families (dense/moe/ssm/hybrid/hybrid_moe/
+vlm/audio).
 
 Scan-over-layers with stacked parameters keeps the HLO size O(1) in depth —
 essential for 40-cell × 2-mesh dry-run compile times — with optional remat
 of the scan body. The hybrid (zamba2-style) stack is structured as
 ``n_sites`` super-blocks (attn_every mamba layers + one *shared* attention
 block) plus trailing mamba layers, so the shared block's KV cache is
-per-site, not per-layer (DESIGN §4).
+per-site, not per-layer (DESIGN §4). The hybrid_moe (Nemotron-H) stack
+follows ``cfg.layer_pattern``: one pre-norm block per character, each with
+one mixer (M Mamba2, E MoE, * attention); its blocks differ in kind, so they
+are a list, unrolled, each block checkpointed.
 """
 
 from __future__ import annotations
@@ -20,7 +24,8 @@ from repro.configs.base import ModelConfig
 from repro.models import ssm
 from repro.models.attention import run_attention
 from repro.models.layers import dense_init, embed_init, rms_norm, swiglu
-from repro.models.moe import moe_ffn
+from repro.models.moe import moe_dropless, moe_ffn
+from repro.obs import scopes
 
 Array = jax.Array
 Params = Any
@@ -98,6 +103,33 @@ def _mamba_layer_init(key, cfg: ModelConfig, dtype):
     }
 
 
+def _expert_share_init(key, cfg: ModelConfig, dtype):
+    """Router over every expert; this chip's held experts (relu², two
+    matrices each) and the shared expert."""
+    ks = jax.random.split(key, 5)
+    d, f, e = cfg.d_model, cfg.d_ff, cfg.held_experts
+
+    def experts(k, din, dout):
+        return (jax.vmap(lambda kk: dense_init(kk, din, dout, dtype))
+                (jax.random.split(k, e)))
+    p = {
+        "router": dense_init(ks[0], d, cfg.num_experts, dtype),
+        "w_up": experts(ks[1], d, f),
+        "w_down": experts(ks[2], f, d),
+    }
+    if cfg.shared_d_ff:
+        p["shared_up"] = dense_init(ks[3], d, cfg.shared_d_ff, dtype)
+        p["shared_down"] = dense_init(ks[4], cfg.shared_d_ff, d, dtype)
+    return p
+
+
+def _pattern_block_init(key, cfg: ModelConfig, kind: str, dtype):
+    mixer = {"M": ("mamba", lambda k: ssm.mamba2_init(k, cfg, dtype)),
+             "E": ("moe", lambda k: _expert_share_init(k, cfg, dtype)),
+             "*": ("attn", lambda k: _attn_init(k, cfg, dtype))}[kind]
+    return {mixer[0]: mixer[1](key), "ln": jnp.ones((cfg.d_model,), dtype)}
+
+
 # ---------------------------------------------------------------------------
 # Per-layer apply
 # ---------------------------------------------------------------------------
@@ -127,6 +159,30 @@ def _mamba_layer(cfg: ModelConfig, params, h, cache=None):
     return h + y, new_cache
 
 
+def _pattern_block(cfg: ModelConfig, kind: str, params, h, cache=None,
+                   pos=None):
+    """One hybrid_moe block: h + mixer(rmsnorm(h)).
+    → (h, new_cache, counters)."""
+    x = rms_norm(h, params["ln"], cfg.norm_eps)
+    counters = {}
+    if kind == "M":
+        y, new_cache = ssm.mamba2_block(params["mamba"], cfg, x, cache)
+    elif kind == "*":
+        y, new_cache = run_attention(
+            params["attn"], x, cfg_heads=cfg.num_heads,
+            cfg_kv=cfg.num_kv_heads, head_dim=cfg.resolved_head_dim,
+            rope_theta=cfg.rope_theta, window=cfg.sliding_window,
+            cache=cache, pos=pos, rope=cfg.rope, remat_blocks=cfg.remat)
+    else:
+        with jax.named_scope(scopes.MOE):
+            y, counters = moe_dropless(
+                params["moe"], x, top_k=cfg.num_experts_per_tok,
+                first=cfg.experts_first, held=cfg.held_experts,
+                routed_scale=cfg.routed_scale)
+        new_cache = None
+    return h + y, new_cache, counters
+
+
 # ---------------------------------------------------------------------------
 # Stack init
 # ---------------------------------------------------------------------------
@@ -142,7 +198,12 @@ def init_params(cfg: ModelConfig, key) -> Params:
         params["lm_head"] = dense_init(k_head, cfg.d_model,
                                        cfg.padded_vocab, dtype)
     L = cfg.num_layers
-    if cfg.family in ("ssm",):
+    if cfg.layer_pattern:
+        params["layers"] = [
+            _pattern_block_init(k, cfg, kind, dtype)
+            for k, kind in zip(jax.random.split(k_layers, L),
+                               cfg.layer_pattern)]
+    elif cfg.family in ("ssm",):
         params["layers"] = jax.vmap(
             lambda k: _mamba_layer_init(k, cfg, dtype))(
                 jax.random.split(k_layers, L))
@@ -195,6 +256,10 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Any:
         return {"k": jnp.zeros((batch, eff_len, hkv, dh), dtype),
                 "v": jnp.zeros((batch, eff_len, hkv, dh), dtype)}
 
+    if cfg.layer_pattern:
+        kinds = {"M": lambda: ssm.mamba2_cache_init(cfg, batch, dtype),
+                 "*": attn_cache, "E": lambda: None}
+        return {"layers": [kinds[k]() for k in cfg.layer_pattern]}
     if cfg.family == "ssm":
         one = ssm.mamba2_cache_init(cfg, batch, dtype)
         return {"layers": jax.tree.map(
@@ -286,9 +351,39 @@ def _shared_block(cfg: ModelConfig, params, h, cache=None, pos=None):
     return h + y, new_cache
 
 
+def _run_pattern(cfg: ModelConfig, params, h: Array, cache=None,
+                 pos: Optional[Array] = None):
+    """The hybrid_moe stack. → (h, new_cache, counters summed over the
+    blocks)."""
+    counters: dict = {}
+    new_caches = []
+    for i, kind in enumerate(cfg.layer_pattern):
+        block = functools.partial(_pattern_block, cfg, kind)
+        c_i = None if cache is None else cache["layers"][i]
+        if cache is None and cfg.remat:
+            block = jax.checkpoint(block)
+        h, c_new, cnt = block(params["layers"][i], h, c_i, pos)
+        new_caches.append(c_new)
+        for name, v in cnt.items():
+            counters[name] = counters.get(name, 0) + v
+    return h, (None if cache is None else {"layers": new_caches}), counters
+
+
 def run_stack(cfg: ModelConfig, params, h: Array, cache=None,
               pos: Optional[Array] = None):
-    """h: [B, S, D] embeddings → (h, new_cache, aux). cache/pos per decode."""
+    """h: [B, S, D] embeddings → (h, new_cache, aux, counters). cache/pos
+    per decode. ``counters`` are what the model counted (hybrid_moe:
+    ``routed_slots``, ``expert_rows`` summed over its E blocks; empty for
+    every other family)."""
+    if cfg.layer_pattern:
+        h, new_cache, counters = _run_pattern(cfg, params, h, cache, pos)
+        return h, new_cache, jnp.float32(0), counters
+    return (*_run_uniform(cfg, params, h, cache, pos), {})
+
+
+def _run_uniform(cfg: ModelConfig, params, h: Array, cache=None,
+                 pos: Optional[Array] = None):
+    """The stacks of one repeated layer kind. → (h, new_cache, aux)."""
     aux_total = jnp.float32(0)
 
     if cfg.family == "ssm":

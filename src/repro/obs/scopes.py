@@ -36,6 +36,8 @@ KERNEL_LAYOUT = "repro.kernel_layout"
 TRANSPORT = "repro.transport"
 #: train step phase 1 and the simulator round's local gradients
 GRADS = "repro.grads"
+#: ``models/moe.py``'s dropless expert layer: router, dispatch, experts
+MOE = "repro.moe"
 #: train step phase 2 and the simulator round's aggregation
 AGGREGATE = "repro.aggregate"
 #: train step phase 3: the flat optimizer and the downlink
@@ -44,7 +46,7 @@ OPTIMIZER = "repro.optimizer"
 EVAL = "repro.eval"
 
 SCOPES = (EXECUTOR, NODE_STEP, TAU_SEARCH, KERNEL_LAYOUT, TRANSPORT, GRADS,
-          AGGREGATE, OPTIMIZER, EVAL)
+          MOE, AGGREGATE, OPTIMIZER, EVAL)
 
 _COMPONENT = re.compile(r"(?:^|[/(])(repro\.[A-Za-z_]+)(?=$|[/)])")
 

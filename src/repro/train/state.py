@@ -35,6 +35,12 @@ class TrainConfig:
     # activation all-reduces. Wins when 2·activations·layers ≫ params
     # (EXPERIMENTS §Perf pair A). SSM/hybrid archs do this regardless.
     fsdp_compute: bool = False
+    # EF as one flat [K_dp·D_pad] vector, each (client, model column)
+    # block contiguous, instead of [K_dp, D_pad]: a [1, D] bfloat16 block
+    # tiles two rows per TPU tile (twice its bytes) and the ring reads it
+    # through a relayout copy in float32; the flat block needs neither.
+    # Flat, single-tenant topologies.
+    ef_flat: bool = False
 
     def needs_tcs(self) -> bool:
         return self.agg.kind in (AggKind.TC_SIA, AggKind.CL_TC_SIA)
@@ -46,6 +52,7 @@ class TrainState(NamedTuple):
     master: Array                   # [D_pad] fp32, fully sharded (ZeRO)
     opt: FlatOptState               # flat, sharded like master
     ef: Array                       # [K_dp, D_pad] per-client error feedback
+                                    # ([K_dp·D_pad] with TrainConfig.ef_flat)
     tcs_prev: Optional[Any]         # params-shaped pytree (TC algorithms)
     # upper-tier EF of a nested (staged) aggregation topology: one
     # [K_dp, D_pad // prod(K_0..K_{s-1})] array per stage ≥ 1 (rank

@@ -181,6 +181,19 @@ def _segment_agg_cfg(tc: TrainConfig, mesh, d_flat: int) -> AggConfig:
     return dataclasses.replace(tc.agg, **kw)
 
 
+def _ef_spec(tc: TrainConfig, mesh) -> P:
+    """Sharding of the per-client EF: a [K_dp, D_pad] array, or with
+    ``tc.ef_flat`` its flat [K_dp·D_pad] form, rank (client, column)
+    owning one contiguous block."""
+    dp = dp_axes(mesh)
+    return P(dp + ("model",)) if tc.ef_flat else P(dp, "model")
+
+
+def _check_ef_flat(tc: TrainConfig, nested, cohorts: int) -> None:
+    if tc.ef_flat and (nested is not None or cohorts > 1):
+        raise ValueError("ef_flat runs flat, single-tenant topologies")
+
+
 def _model_axis_index(mesh):
     if "model" in mesh.axis_names:
         return jax.lax.axis_index("model")
@@ -242,11 +255,13 @@ def init_state(cfg: ModelConfig, tc: TrainConfig, mesh, rng,
     layout = make_layout(cfg, mesh)
     k_dp = dp_size(mesh)
     _, nested, n_axes = _resolve_topology(mesh, topology)
+    _check_ef_flat(tc, nested, cohorts)
     params = model_mod.init_params(cfg, rng)
     order = None if nested is None else _stage_order(n_axes)
     master = _master_from_params(cfg, mesh, layout, params, order=order)
     opt = opt_mod.init_flat(tc.opt, layout.d_flat, like=master)
-    ef = jnp.zeros((k_dp, layout.d_flat), jnp.dtype(tc.ef_dtype))
+    ef = jnp.zeros((k_dp * layout.d_flat,) if tc.ef_flat
+                   else (k_dp, layout.d_flat), jnp.dtype(tc.ef_dtype))
     stage_ef = None
     if nested is not None:
         stage_ef = tuple(
@@ -293,7 +308,7 @@ def state_shardings(cfg: ModelConfig, tc: TrainConfig, mesh,
         params=p_specs,
         master=ns(fs),
         opt=opt_mod.FlatOptState(step=ns(P()), m=opt_m, v=opt_v),
-        ef=ns(P(dp, "model")),
+        ef=ns(_ef_spec(tc, mesh)),
         tcs_prev=tcs,
         stage_ef=stage_ef,
     )
@@ -357,6 +372,8 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh,
     seg = layout.n_local // k_dp
     agg_cfg = _segment_agg_cfg(tc, mesh, layout.d_flat)
     _, nested_plan, n_axes = _resolve_topology(mesh, topology)
+    _check_ef_flat(tc, nested_plan, cohorts)
+    ef_sp = _ef_spec(tc, mesh)
     if cohorts > 1 and nested_plan is not None:
         raise ValueError("cohort batches run flat topologies; nested "
                          "plans train per tenant")
@@ -409,8 +426,10 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh,
         (loss, metrics), grads = jax.value_and_grad(
             local_loss, has_aux=True)(params)
         loss = jax.lax.pmean(loss, dp)
+        # the model's counters (empty for most families), over all clients
+        counters = jax.lax.psum(metrics["counters"], dp)
         grads = jax.tree.map(lambda g: g[None], grads)   # stack client axis
-        return grads, loss
+        return grads, loss, counters
 
     # ---- phase 2: sparse incremental aggregation (flat, local layout) -----
     def _col_and_mask(grads_tree, params_tree, prev_tree):
@@ -443,12 +462,12 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh,
     def ring_fn(grads_tree, ef_l, w_l, part_l, params_tree, prev_tree):
         col, mask_col = _col_and_mask(grads_tree, params_tree, prev_tree)
         final, ef_new, stats = run_plan_segments_local(
-            agg_cfg, agg_plan, col, ef_l[0], w_l[0], axis=dp,
-            global_mask_local=mask_col, participate=part_l[0],
-            transport="static")
+            agg_cfg, agg_plan, col, ef_l if tc.ef_flat else ef_l[0],
+            w_l[0], axis=dp, global_mask_local=mask_col,
+            participate=part_l[0], transport="static")
         stats = jax.tree.map(
             lambda s: jax.lax.psum(s, tuple(manual_axes)), stats)
-        return final, ef_new[None], stats
+        return final, ef_new if tc.ef_flat else ef_new[None], stats
 
     def nested_ring_fn(grads_tree, ef_l, se_l, w_l, part_l, params_tree,
                        prev_tree):
@@ -490,7 +509,7 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh,
 
         # phase 1 — per-client grads (model axis auto inside)
         with jax.named_scope(scopes.GRADS):
-            grads_stacked, loss = compat.shard_map(
+            grads_stacked, loss, counters = compat.shard_map(
                 per_client,
                 mesh=mesh,
                 in_specs=(jax.tree.map(lambda _: P(), state.params),
@@ -498,7 +517,8 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh,
                               lambda l: P(dp, *([None] * (l.ndim - 1))),
                               batch)),
                 out_specs=(jax.tree.map(
-                    lambda l: P(dp, *([None] * l.ndim)), state.params), P()),
+                    lambda l: P(dp, *([None] * l.ndim)), state.params), P(),
+                    P()),
                 axis_names=set(dp),
             )(state.params, batch)
 
@@ -516,10 +536,10 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh,
                 agg_flat, ef_new, stats = compat.shard_map(
                     ring_fn,
                     mesh=mesh,
-                    in_specs=(layout.grads_in_specs(dp), P(dp, "model"), P(dp),
+                    in_specs=(layout.grads_in_specs(dp), ef_sp, P(dp),
                               P(dp), layout.param_in_specs(),
                               layout.param_in_specs()),
-                    out_specs=(fs, P(dp, "model"), stats_specs),
+                    out_specs=(fs, ef_sp, stats_specs),
                     axis_names=manual_axes,
                 )(grads_stacked, state.ef, weights, participate, params_in,
                   prev_in)
@@ -568,6 +588,7 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh,
             "agg_nnz": stats.nnz,
             "agg_err_sq": stats.err_sq,
             "lr_scale": lr_scale,
+            **counters,
         }
         if relay_bits is not None:
             # the scarce-link tier (pod-seam DCI / inter-cluster relay)
@@ -631,11 +652,11 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh,
         # without a vmapped batch dim; grads are embarrassingly parallel so
         # looping loses nothing — phase 2 is where cohorts share the wire)
         with jax.named_scope(scopes.GRADS):
-            g_list, l_list = [], []
+            g_list, l_list, c_list = [], [], []
             for i in range(b_coh):
                 params_i = jax.tree.map(lambda p: p[i], state.params)
                 batch_i = jax.tree.map(lambda x: x[i], batch)
-                g_i, l_i = compat.shard_map(
+                g_i, l_i, c_i = compat.shard_map(
                     per_client,
                     mesh=mesh,
                     in_specs=(jax.tree.map(lambda _: P(), params_i),
@@ -643,13 +664,16 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh,
                                   lambda l: P(dp, *([None] * (l.ndim - 1))),
                                   batch_i)),
                     out_specs=(jax.tree.map(
-                        lambda l: P(dp, *([None] * l.ndim)), params_i), P()),
+                        lambda l: P(dp, *([None] * l.ndim)), params_i), P(),
+                        P()),
                     axis_names=set(dp),
                 )(params_i, batch_i)
                 g_list.append(g_i)
                 l_list.append(l_i)
+                c_list.append(c_i)
             grads_stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *g_list)
             loss = jnp.stack(l_list)
+            counters = jax.tree.map(lambda *xs: jnp.stack(xs), *c_list)
 
         # phase 2 — batched ring aggregation: B cohorts, one wavefront
         with jax.named_scope(scopes.AGGREGATE):
@@ -701,6 +725,7 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh,
             "agg_nnz": stats.nnz,
             "agg_err_sq": stats.err_sq,
             "lr_scale": lr_scale,
+            **counters,
         }
         with jax.named_scope(scopes.AGGREGATE):
             if telemetry:

@@ -90,7 +90,7 @@ def test_partition_dirichlet_skews_labels():
 
 
 def test_registry_complete():
-    assert len(ARCHS) == 10
+    assert len(ARCHS) == 11
     for a in ARCHS:
         cfg = get_config(a)
         assert cfg.name == a
@@ -103,6 +103,7 @@ PUBLISHED_N = {  # billions, loose tolerance (head/frontend conventions vary)
     "glm4-9b": (9.4, 0.1), "phi4-mini-3.8b": (3.84, 0.1),
     "mixtral-8x7b": (46.7, 0.05), "zamba2-1.2b": (1.22, 0.1),
     "mamba2-130m": (0.13, 0.1), "musicgen-medium": (1.5, 0.25),
+    "nemotron3-nano-30b-a3b": (31.6, 0.05),
 }
 
 
@@ -120,8 +121,9 @@ def test_moe_active_counts():
 
 def test_shape_cells_assignment():
     total = sum(len(shape_cells(get_config(a))) for a in ARCHS)
-    assert total == 33  # 10×3 + 3 sub-quadratic long_500k
+    assert total == 37  # 11×3 + 4 sub-quadratic long_500k
     assert "long_500k" in shape_cells(get_config("mamba2-130m"))
+    assert "long_500k" in shape_cells(get_config("nemotron3-nano-30b-a3b"))
     assert "long_500k" in shape_cells(get_config("zamba2-1.2b"))
     assert "long_500k" in shape_cells(get_config("mixtral-8x7b"))
     assert "long_500k" not in shape_cells(get_config("granite-34b"))

@@ -20,7 +20,7 @@ import pytest
 from repro import compat
 from repro.agg import compile_plan, execute
 from repro.agg.device import ring_chain_plan
-from repro.configs import PAPER
+from repro.configs import PAPER, get_config
 from repro.configs.base import ModelConfig
 from repro.core.algorithms import AggConfig, AggKind
 from repro.data.federated import partition_iid
@@ -42,6 +42,8 @@ PC = dataclasses.replace(PAPER, num_clients=K)
      scopes.GRADS),
     ("jit(f)/jvp(repro.aggregate)/repro.tau_search/top_k",
      scopes.TAU_SEARCH),
+    ("jit(f)/repro.grads/transpose(jvp(repro.moe))/dot_general",
+     scopes.MOE),
     ("jit(f)/repro.executors/add", None),
     ("jit(f)/repro.unknown/add", None),
     ("jit(f)/add", None),
@@ -69,12 +71,12 @@ def _round_text(topq: str) -> str:
                     jnp.full((K,), 1.0 / K)).compile().as_text()
 
 
-def _train_text() -> str:
+def _train_text(cfg=None) -> str:
     mesh = compat.make_mesh((1, 1), ("data", "model"))
-    cfg = ModelConfig(name="tiny", family="ssm", num_layers=2, d_model=32,
-                      num_heads=0, num_kv_heads=0, d_ff=0, vocab_size=64,
-                      ssm_state=16, ssm_headdim=16, ssm_expand=2,
-                      ssm_chunk=16, ssm_conv=4, param_dtype="float32")
+    cfg = cfg or ModelConfig(
+        name="tiny", family="ssm", num_layers=2, d_model=32, num_heads=0,
+        num_kv_heads=0, d_ff=0, vocab_size=64, ssm_state=16, ssm_headdim=16,
+        ssm_expand=2, ssm_chunk=16, ssm_conv=4, param_dtype="float32")
     tc = TrainConfig(agg=AggConfig(kind=AggKind.CL_SIA, q=1,
                                    topq_impl="exact"),
                      opt=OptConfig(name="adamw", lr=1e-3), q_frac=0.05,
@@ -115,6 +117,11 @@ PROGRAMS = {
     "train_step": (lambda fed: _train_text(),
                    {scopes.GRADS, scopes.AGGREGATE, scopes.NODE_STEP,
                     scopes.TAU_SEARCH, scopes.OPTIMIZER}),
+    "train_step_moe": (lambda fed: _train_text(
+                           get_config("nemotron3-nano-30b-a3b", smoke=True)),
+                       {scopes.GRADS, scopes.MOE, scopes.AGGREGATE,
+                        scopes.NODE_STEP, scopes.TAU_SEARCH,
+                        scopes.OPTIMIZER}),
     "sim_round": (_sim_text,
                   {scopes.GRADS, scopes.AGGREGATE, scopes.EXECUTOR,
                    scopes.NODE_STEP, scopes.TAU_SEARCH, scopes.EVAL}),
