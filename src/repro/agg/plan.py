@@ -24,6 +24,7 @@ steps run the paper's static-``q`` exact Top-Q, bit-identical to before.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import NamedTuple, Optional, Sequence, Union
 
@@ -31,8 +32,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.algorithms import (AggConfig, AggKind, HopStats, level_step,
+from repro.core.algorithms import (AggConfig, AggKind, HopStats,
+                                   fused_node_steps, level_step,
                                    level_step_batched)
+from repro.kernels import ops as kops
+from repro.kernels.level import from_blocks, to_blocks
 from repro.obs import scopes
 from repro.topo.tree import PS, AggTree, build_schedule, path_tree
 
@@ -241,8 +245,15 @@ class RoundResult(NamedTuple):
     stats: HopStats       # per-hop stats, leaves [K] (client index order)
 
 
+#: Trace-time record of the layout each traced :func:`execute` carried its
+#: round in: ``(layout, W, d)`` → times traced, layout ``"blocked"`` (the
+#: kernels' block layout from round entry to exit) or ``"rows"`` ([·, d]).
+EXECUTOR_LAYOUTS: collections.Counter = collections.Counter()
+
+
 def _take_rows(x: Array, ids: Array) -> Array:
-    """``x[ids]`` for a [R, d] array and a [n] index vector, as n row slices.
+    """``x[ids]`` for a [R, ...] array and a [n] index vector, as n row
+    slices.
 
     The TPU compiler splits a gather of whole rows into d/32768 unrolled
     pieces (512 at d=2^24), which costs seconds of compile time per
@@ -271,8 +282,19 @@ def execute(
     of a level concurrently — the historic ``vmap`` of the scalar node
     step off-TPU, one batched Pallas call per level when the fused kernel
     path is on; children's partial aggregates merge at each parent via a
-    masked scatter-add (padding slots run the zero dummy row and target
-    the trash row, so they are no-ops).
+    masked scatter-add, and each node's e′ is written over its own EF row,
+    which no later level reads. Padding slots (node id K) run with weight
+    and participation 0, read row K−1 (a row take clamps) and target the
+    trash row; ``level_step`` gives such ``valid == 0`` lanes zero outputs
+    and counts whatever their input rows hold, so they are no-ops.
+
+    When the levels run the Pallas kernels (the fused algorithms, float32
+    compute), the round is carried in the kernels' block layout from entry
+    to exit: ``grads``, ``e`` and the mask are padded once into
+    [·, n_blocks, 8, 1024] blocks (:func:`repro.kernels.level.to_blocks`),
+    every row take, scatter-add and e′ write works on dense tiles, and the
+    results are cut back to [·, d] once. The pad entries are zeros and
+    stay zeros through every hop, so the values are those of the rows.
     """
     k, d = grads.shape
     if plan.num_clients != k:
@@ -283,11 +305,19 @@ def execute(
         participate = jnp.ones((k,), grads.dtype)
     participate = participate * jnp.asarray(plan.alive, grads.dtype)
     lvl = level_step(cfg)
+    blocked = bool(fused_node_steps(cfg, weights, grads, e)
+                   and kops.resolve(cfg.kernel_mode)[0])
+    EXECUTOR_LAYOUTS["blocked" if blocked else "rows", plan.shape[1], d] += 1
+    # e′ takes the promoted dtype of the node step's inputs
+    e = e.astype(jnp.result_type(weights, grads, e, participate,
+                                 global_mask))
+    if blocked:
+        grads, e = to_blocks(grads), to_blocks(e)
+        global_mask = to_blocks(global_mask)
+    lane = (slice(None),) + (None,) * (grads.ndim - 1)
 
-    # one zero dummy row (index K) backs the padding slots
-    zrow = jnp.zeros((1, d), grads.dtype)
-    g_ext = jnp.concatenate([grads, zrow])
-    e_ext = jnp.concatenate([e, zrow])
+    # a dummy entry (index K) gives the padding slots weight and
+    # participation 0
     w_ext = jnp.concatenate([weights, jnp.zeros((1,), weights.dtype)])
     p_ext = jnp.concatenate(
         [participate, jnp.zeros((1,), participate.dtype)])
@@ -296,30 +326,35 @@ def execute(
         q_ext = jnp.concatenate([jnp.asarray(plan.q_budget, jnp.int32),
                                  jnp.zeros((1,), jnp.int32)])
 
-    def body(inbox, xs):
+    def body(carry, xs):
+        inbox, e = carry
         ids, mask, par = xs
         gamma_out, e_new, stats = lvl(
-            _take_rows(g_ext, ids), _take_rows(inbox, ids),
-            _take_rows(e_ext, ids), w_ext[ids], p_ext[ids],
-            global_mask, None if q_ext is None else q_ext[ids], mask)
-        inbox = inbox.at[par].add(gamma_out * mask[:, None])
-        return inbox, (e_new, stats)
+            _take_rows(grads, ids), _take_rows(inbox, ids),
+            _take_rows(e, ids), w_ext[ids], p_ext[ids],
+            global_mask, None if q_ext is None else q_ext[ids], mask, d=d)
+        inbox = inbox.at[par].add(gamma_out * mask[lane])
+        if e_new.dtype != e.dtype:
+            raise TypeError(f"e′ is {e_new.dtype}, its rows {e.dtype}")
+        e = e.at[ids].set(e_new, mode="drop")
+        return (inbox, e), stats
 
     # inbox rows: 0..K−1 per-client incoming sums, K..K+R−1 the sink rows
     # (R = 1: the PS), K+R = trash
     r_sinks = plan.num_sinks
-    inbox0 = jnp.zeros((k + r_sinks + 1, d), grads.dtype)
-    inbox, (e_lvl, st_lvl) = jax.lax.scan(
-        body, inbox0,
+    inbox0 = jnp.zeros((k + r_sinks + 1,) + grads.shape[1:], grads.dtype)
+    (inbox, e_new), st_lvl = jax.lax.scan(
+        body, (inbox0, e),
         (jnp.asarray(plan.node_id), jnp.asarray(plan.slot_mask),
          jnp.asarray(plan.parent_row)))
 
-    # scan outputs are [L, W, ...] in schedule order → client index order
+    # stats are [L, W] in schedule order → client index order
     pos = jnp.asarray(plan.flat_pos)
-    e_new = _take_rows(e_lvl.reshape(-1, d), pos)
     stats = jax.tree.map(
         lambda s: s.reshape((-1,) + s.shape[2:])[pos], st_lvl)
     agg = inbox[k] if r_sinks == 1 else inbox[k:k + r_sinks]
+    if blocked:
+        e_new, agg = from_blocks(e_new, d), from_blocks(agg, d)
     return RoundResult(aggregate=agg, e_new=e_new, stats=stats)
 
 

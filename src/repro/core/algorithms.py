@@ -277,12 +277,18 @@ def _lane_inf(w: int) -> Array:
     return jnp.full((w,), jnp.inf, jnp.float32)
 
 
+def _per_lane(v: Array, x: Array) -> Array:
+    """A [W] per-lane vector shaped to broadcast over the lanes of ``x``
+    ([W, d] rows or [W, n_blocks, SUBLANES, LANES] blocks)."""
+    return v[(slice(None),) + (None,) * (x.ndim - 1)]
+
+
 _tau_scope = jax.named_scope(scopes.TAU_SEARCH)
 _node_scope = jax.named_scope(scopes.NODE_STEP)
 
 
-def _tau_operand(cfg: AggConfig, g, e, gam, w, p, gm=None, cohorts=0, *,
-                 include_gamma: bool = False) -> sp.TauOperand:
+def _tau_operand(cfg: AggConfig, d: int, g, e, gam, w, p, gm=None,
+                 cohorts=0, *, include_gamma: bool = False) -> sp.TauOperand:
     """Build the level's bisection operand from the raw node inputs.
 
     The returned :class:`repro.core.sparsify.TauOperand` streams candidate
@@ -293,8 +299,13 @@ def _tau_operand(cfg: AggConfig, g, e, gam, w, p, gm=None, cohorts=0, *,
     full sort anyway) and ``max_abs()`` use the identical float expression
     (:func:`repro.kernels.ref.fused_operand`), so every path stays bitwise
     interchangeable with the historic materialized-x search.
+
+    Blocked inputs (the executor's round, ``d`` entries of data per lane
+    and zeros after them) give a blocked ``materialize()``; the counts
+    leave the pad zeros out, as the row wrappers' do.
     """
     mode = cfg.kernel_mode
+    pad = g[0].size - d
 
     @_tau_scope
     def materialize():
@@ -304,19 +315,24 @@ def _tau_operand(cfg: AggConfig, g, e, gam, w, p, gm=None, cohorts=0, *,
 
     @_tau_scope
     def count(taus):
-        return kops.count_ge_fused_level(
+        counts = kops.count_ge_fused_level(
             g, e, gam, w, p, taus, gm, include_gamma=include_gamma,
             gmask_cohorts=cohorts, mode=mode)
+        if pad:     # blocked inputs: |0| >= tau only where tau <= 0
+            counts = counts - jnp.where(taus <= 0, jnp.int32(pad),
+                                        jnp.int32(0))
+        return counts
 
     @_tau_scope
     def max_abs():
         # XLA fuses the elementwise operand into the reduce — one streaming
         # pass, no [W, d] landing in HBM; bitwise equal to a materialized
-        # jnp.max(jnp.abs(x)) (same expression, same reduction)
+        # jnp.max(jnp.abs(x)) (same expression; a max is exact in any
+        # order, so the blocked layout reduces to the same value)
         mag = jnp.abs(materialize())
         if not mag.size:
-            return jnp.zeros(mag.shape[:-1], jnp.float32)
-        return jnp.max(mag, axis=-1)
+            return jnp.zeros(mag.shape[:1], jnp.float32)
+        return jnp.max(mag, axis=tuple(range(1, mag.ndim)))
 
     @_tau_scope
     def hist(tables):
@@ -346,18 +362,24 @@ def _lane_sparsifier_state(cfg: AggConfig, operand: sp.TauOperand, q: int,
     sparsify_ef stage then banks the whole g̃ into error feedback, exactly
     the unfused straggler algebra. (The CL kernels override stragglers
     internally, where this zeroing is a harmless no-op.)
+
+    A blocked operand is selected flat: index order is kept and its pad
+    zeros come last, so ties break as on the rows.
     """
     w = p.shape[0]
-    if qb is not None:
-        mask = jax.vmap(sp.topq_mask_dynamic)(operand.materialize(), qb)
-        return mask * p[:, None], _lane_inf(w)
-    if cfg.topq_impl == "threshold":
+    if cfg.topq_impl == "threshold" and qb is None:
         tau = sp.threshold_for_topq(
             None, q, branch=cfg.hist_branch, rounds=cfg.hist_rounds,
             operand_fn=operand, tau_impl=cfg.tau_impl)
         return None, jnp.where(p > 0, tau, jnp.inf)
-    mask = sp.topq_mask(operand.materialize(), q)
-    return mask * p[:, None], _lane_inf(w)
+    x = operand.materialize()
+    flat = x.reshape(w, -1)
+    if qb is not None:
+        mask = jax.vmap(sp.topq_mask_dynamic)(flat, qb)
+    else:
+        mask = sp.topq_mask(flat, q)
+    mask = mask.reshape(x.shape)
+    return mask * _per_lane(p, mask), _lane_inf(w)
 
 
 def _lane_err_sq(e_new: Array) -> Array:
@@ -375,14 +397,15 @@ def _stats_no_gmask(cfg: AggConfig, d: int, nnz: Array, e_new: Array,
 def _stats_gmask(cfg: AggConfig, d: int, gm: Array, nnz: Array,
                  nnz_off: Array, e_new: Array, cohorts: int = 0,
                  err: Optional[Array] = None) -> HopStats:
-    if gm.ndim == 1:       # lane-shared mask: one count, broadcast
+    if gm.ndim < e_new.ndim:      # lane-shared mask: one count, broadcast
         nz_g = jnp.broadcast_to(jnp.sum(gm > 0).astype(jnp.int32),
                                 nnz.shape)
     elif cohorts and gm.shape[0] != nnz.shape[0]:
         # cohort-shared [B, d] mask over B*W cohort-major lanes: one count
         # per cohort, tiled to its W lanes — the same per-row reduction the
         # sequential lane-shared branch runs, so bitwise comparable
-        nz_gc = jnp.sum(gm > 0, axis=-1).astype(jnp.int32)
+        nz_gc = jnp.sum(gm > 0, axis=tuple(range(1, gm.ndim))).astype(
+            jnp.int32)
         nz_g = jnp.repeat(nz_gc, nnz.shape[0] // cohorts)
     else:
         nz_g = jax.vmap(
@@ -405,9 +428,8 @@ def _gm_rows(gm: Array, lanes: int, cohorts: int) -> Array:
     return gm
 
 
-def _fused_level_sia(cfg, g, gam, e, w, p, gm, qb, valid, cohorts=0):
-    d = g.shape[-1]
-    op = _tau_operand(cfg, g, e, None, w, p)
+def _fused_level_sia(cfg, d, g, gam, e, w, p, gm, qb, valid, cohorts=0):
+    op = _tau_operand(cfg, d, g, e, None, w, p)
     mask, tau = _lane_sparsifier_state(cfg, op, cfg.q, p, qb)
     we = cfg.err_sq_mode == "kernel"
     out = kops.sparsify_ef_level(g, e, mask, w, tau, valid, with_err=we,
@@ -419,17 +441,17 @@ def _fused_level_sia(cfg, g, gam, e, w, p, gm, qb, valid, cohorts=0):
                                         out[3] if we else None)
 
 
-def _fused_level_re_sia(cfg, g, gam, e, w, p, gm, qb, valid, cohorts=0):
-    d = g.shape[-1]
-    op = _tau_operand(cfg, g, e, None, w, p)
+def _fused_level_re_sia(cfg, d, g, gam, e, w, p, gm, qb, valid, cohorts=0):
+    op = _tau_operand(cfg, d, g, e, None, w, p)
     m_in = sp.support(gam)
     if qb is None and cfg.topq_impl == "threshold":
         _, tau = _lane_sparsifier_state(cfg, op, cfg.q, p, qb)
-        mask = m_in * p[:, None]
+        mask = m_in * _per_lane(p, m_in)
     else:
         m_l, tau = _lane_sparsifier_state(cfg, op, cfg.q,
                                           jnp.ones_like(p), qb)
-        mask = sp.mask_union(m_l, m_in) * p[:, None]
+        mask = sp.mask_union(m_l, m_in)
+        mask = mask * _per_lane(p, mask)
     we = cfg.err_sq_mode == "kernel"
     out = kops.sparsify_ef_level(g, e, mask, w, tau, valid, with_err=we,
                                  mode=cfg.kernel_mode)
@@ -440,10 +462,9 @@ def _fused_level_re_sia(cfg, g, gam, e, w, p, gm, qb, valid, cohorts=0):
                                         out[3] if we else None)
 
 
-def _fused_level_tc_sia(cfg, g, gam, e, w, p, gm, qb, valid, cohorts=0):
-    d = g.shape[-1]
+def _fused_level_tc_sia(cfg, d, g, gam, e, w, p, gm, qb, valid, cohorts=0):
     gme = _gm_rows(gm, g.shape[0], cohorts)
-    op = _tau_operand(cfg, g, e, None, w, p, gm, cohorts)
+    op = _tau_operand(cfg, d, g, e, None, w, p, gm, cohorts)
     m_k, tau = _lane_sparsifier_state(cfg, op, cfg.q_local,
                                       jnp.ones_like(p), qb)
     m_in = jnp.clip(sp.support(gam) - gme, 0, 1)
@@ -451,10 +472,10 @@ def _fused_level_tc_sia(cfg, g, gam, e, w, p, gm, qb, valid, cohorts=0):
         # threshold impl: materialize the local mask to union it with the
         # global/incoming masks (matches the unfused topq_mask_fn exactly)
         x = op.materialize()
-        m_k = (jnp.abs(x) >= tau[:, None]).astype(x.dtype)
+        m_k = (jnp.abs(x) >= _per_lane(tau, x)).astype(x.dtype)
         tau = _lane_inf(g.shape[0])
     mm = sp.mask_union(gme, m_k, m_in)
-    mask = mm * p[:, None]
+    mask = mm * _per_lane(p, mm)
     we = cfg.err_sq_mode == "kernel"
     out = kops.sparsify_ef_level(g, e, mask, w, tau, valid, with_err=we,
                                  mode=cfg.kernel_mode)
@@ -466,9 +487,8 @@ def _fused_level_tc_sia(cfg, g, gam, e, w, p, gm, qb, valid, cohorts=0):
                                      cohorts, out[3] if we else None)
 
 
-def _fused_level_cl_sia(cfg, g, gam, e, w, p, gm, qb, valid, cohorts=0):
-    d = g.shape[-1]
-    op = _tau_operand(cfg, g, e, gam, w, p, include_gamma=True)
+def _fused_level_cl_sia(cfg, d, g, gam, e, w, p, gm, qb, valid, cohorts=0):
+    op = _tau_operand(cfg, d, g, e, gam, w, p, include_gamma=True)
     mask, tau = _lane_sparsifier_state(cfg, op, cfg.q, jnp.ones_like(p),
                                        qb)
     we = cfg.err_sq_mode == "kernel"
@@ -479,9 +499,8 @@ def _fused_level_cl_sia(cfg, g, gam, e, w, p, gm, qb, valid, cohorts=0):
                                         out[4] if we else None)
 
 
-def _fused_level_cl_tc_sia(cfg, g, gam, e, w, p, gm, qb, valid, cohorts=0):
-    d = g.shape[-1]
-    op = _tau_operand(cfg, g, e, gam, w, p, gm, cohorts,
+def _fused_level_cl_tc_sia(cfg, d, g, gam, e, w, p, gm, qb, valid, cohorts=0):
+    op = _tau_operand(cfg, d, g, e, gam, w, p, gm, cohorts,
                       include_gamma=True)
     mask, tau = _lane_sparsifier_state(cfg, op, cfg.q_local,
                                        jnp.ones_like(p), qb)
@@ -505,7 +524,7 @@ _FUSED_LEVEL = {
 
 @_node_scope
 def _run_fused_level(cfg, g, gamma_in, e, weight, participate, global_mask,
-                     q_budget, valid, cohorts=0):
+                     q_budget, valid, cohorts=0, d=None):
     w_lanes = g.shape[0]
     # a 1-D (lane-shared) TCS mask stays 1-D all the way into the kernels:
     # the level kernels stream it once per block (shared block spec)
@@ -517,8 +536,8 @@ def _run_fused_level(cfg, g, gamma_in, e, weight, participate, global_mask,
     v = (jnp.ones((w_lanes,), jnp.float32) if valid is None
          else _f32(valid))
     gout, e_new, stats = _FUSED_LEVEL[cfg.kind](
-        cfg, _f32(g), _f32(gamma_in), _f32(e), _f32(weight),
-        _f32(participate), gm, qb, v, cohorts)
+        cfg, g.shape[-1] if d is None else d, _f32(g), _f32(gamma_in),
+        _f32(e), _f32(weight), _f32(participate), gm, qb, v, cohorts)
     # padding lanes count nothing — the kernels already zero their outputs
     # and nnz accumulators, but the jnp-side global-mask word count
     # (nnz_global → bits) is lane-agnostic and must be masked to keep the
@@ -735,15 +754,25 @@ def level_step(cfg: AggConfig):
     Pallas kernels of :mod:`repro.kernels.level`, skipping ``valid == 0``
     padding lanes; otherwise it is exactly the historic ``vmap`` of the
     scalar node step (bit-identical to the pre-fusion executors).
+
+    On the fused path the operands may come in the kernels' block layout
+    instead (:func:`repro.kernels.level.to_blocks`: every [d] becomes
+    [n_blocks, SUBLANES, LANES], the mask too), with ``d`` giving the
+    entries of data; the outputs then come blocked as well.
     """
     step = node_step(cfg)
 
     def run(g, gamma_in, e, weight, participate, global_mask,
-            q_budget=None, valid=None):
-        if fused_node_steps(cfg, weight, g, e, gamma_in):
+            q_budget=None, valid=None, d=None):
+        fused = fused_node_steps(cfg, weight, g, e, gamma_in)
+        if g.ndim != 2 and not (fused and kops.resolve(cfg.kernel_mode)[0]):
+            raise ValueError(f"blocked operands {g.shape} need the Pallas "
+                             f"level kernels; {cfg.kind.value} with "
+                             f"kernel_mode={cfg.kernel_mode!r} runs rows")
+        if fused:
             return _run_fused_level(cfg, g, gamma_in, e, weight,
                                     participate, global_mask, q_budget,
-                                    valid)
+                                    valid, d=d)
         shared_mask = getattr(global_mask, "ndim", 1) == 1
 
         def one(g_r, gam_r, e_r, w_r, p_r, *rest):

@@ -15,11 +15,19 @@ block axis; the wrappers reduce them. Every block is either the whole
 array in its last two dimensions or (8, 128)-aligned, which is what the
 TPU compiler accepts.
 
+Each wrapper takes its d-sized operands as ``[W, d]`` rows, which it
+pads into ``[W, n_blocks, SUBLANES, LANES]`` blocks and cuts back after
+the call, or already in that block layout (:func:`to_blocks`; a shared
+``[d]`` mask as ``[n_blocks, SUBLANES, LANES]``), told apart by rank: a
+blocked operand passes in as it is and its outputs come back blocked. A
+caller that runs many calls over the same data (the executor's round)
+pads once instead of once per call; the pad entries are then its zeros.
+
 Padding lanes (``valid == 0`` — the schedule's no-op slots) skip the
 elementwise math entirely (``pl.when``) and write zeros, which keeps the
 executors' masked scatter-adds no-ops. The DMA for a skipped lane still
-runs (block specs are static); the saved work is the VPU math and the
-output traffic semantics stay identical to computing on the zero dummy row.
+runs (block specs are static); the saved work is the VPU math, and the
+outputs are zeros whatever the lane's input rows hold.
 
 Cohort batching (multi-tenant rounds) flattens B shape-identical levels
 into one launch: lanes are laid out cohort-major (``[B*W, d]`` — cohort b
@@ -70,24 +78,45 @@ VREG = 128                  # lanes of one vector register
 _layout_scope = jax.named_scope(scopes.KERNEL_LAYOUT)
 
 
-@_layout_scope
-def _pad_lanes(v: jax.Array, n_blocks: int, pad: int):
-    """[W, d] → [W, n_blocks, SUBLANES, LANES] (zero padded)."""
-    w = v.shape[0]
-    return jnp.pad(v, ((0, 0), (0, pad))).reshape(
-        w, n_blocks, SUBLANES, LANES)
-
-
-@_layout_scope
-def _pad_shared(v: jax.Array, n_blocks: int, pad: int):
-    """[d] → [n_blocks, SUBLANES, LANES] (zero padded) — a lane-shared
-    operand streamed once per block instead of once per (lane, block)."""
-    return jnp.pad(v, (0, pad)).reshape(n_blocks, SUBLANES, LANES)
-
-
 def _geometry(d: int):
     n_blocks = max(1, -(-d // BLOCK))
     return n_blocks, n_blocks * BLOCK - d
+
+
+@_layout_scope
+def to_blocks(x: jax.Array) -> jax.Array:
+    """[..., d] → [..., n_blocks, SUBLANES, LANES], zero padded past d: the
+    kernels' block layout, in which every (8, 128) vector tile is dense."""
+    n_blocks, pad = _geometry(x.shape[-1])
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+    return x.reshape(x.shape[:-1] + (n_blocks, SUBLANES, LANES))
+
+
+@_layout_scope
+def from_blocks(x: jax.Array, d: int) -> jax.Array:
+    """[..., n_blocks, SUBLANES, LANES] → [..., d]: the inverse of
+    :func:`to_blocks`."""
+    return x.reshape(x.shape[:-3] + (-1,))[..., :d]
+
+
+def _lanes_in(v: jax.Array) -> jax.Array:
+    """A lane operand in f32 blocks: [W, d] rows are padded here, a
+    [W, n_blocks, SUBLANES, LANES] operand the caller blocked passes as it
+    is (its pad entries, zeros, count as data)."""
+    v = v.astype(jnp.float32)
+    return v if v.ndim == 4 else to_blocks(v)
+
+
+def _lanes_out(x: jax.Array, v: jax.Array) -> jax.Array:
+    """A blocked kernel output in the layout of its operand ``v``."""
+    return x if v.ndim == 4 else from_blocks(x, v.shape[-1])
+
+
+def _grid(v: jax.Array):
+    """(W, n_blocks, pad) of a lane operand in either layout."""
+    if v.ndim == 4:
+        return v.shape[0], v.shape[1], 0
+    return (v.shape[0],) + _geometry(v.shape[-1])
 
 
 def _blk():
@@ -164,31 +193,27 @@ def _lane_err(acc):
 
 
 @_layout_scope
-def _unpad(x, w_lanes: int, d: int):
-    """[W, n_blocks, SUBLANES, LANES] → [W, d]: the inverse of
-    :func:`_pad_lanes`."""
-    return x.reshape(w_lanes, -1)[:, :d]
-
-
-@_layout_scope
-def _gmask_operand(gmask, w_lanes: int, gmask_cohorts: int, n_blocks: int,
-                   pad: int):
-    """Pick the (padded operand, block spec) for a TC global mask.
+def _gmask_operand(gmask, w_lanes: int, gmask_cohorts: int):
+    """Pick the (blocked operand, block spec) for a TC global mask.
 
     [d] → lane-shared; [B, d] with ``gmask_cohorts == B`` → cohort-shared
-    (requires ``w_lanes % B == 0``); [W, d] → per-lane.
+    (requires ``w_lanes % B == 0``); [W, d] → per-lane. Each may come
+    blocked instead, ``[d]`` as [n_blocks, SUBLANES, LANES] and the others
+    with their leading axis in front.
     """
-    if gmask.ndim == 1:
-        return _pad_shared(gmask.astype(jnp.float32), n_blocks, pad), \
-            _blk_shared()
+    blocked = gmask.ndim >= 3
+    gm = gmask.astype(jnp.float32)
+    if not blocked:
+        gm = to_blocks(gm)
+    if gmask.ndim in (1, 3):
+        return gm, _blk_shared()
     if gmask_cohorts:
         if gmask.shape[0] != gmask_cohorts or w_lanes % gmask_cohorts:
             raise ValueError(
                 f"cohort gmask {gmask.shape} incompatible with "
                 f"{w_lanes} lanes / {gmask_cohorts} cohorts")
-        return _pad_lanes(gmask.astype(jnp.float32), n_blocks, pad), \
-            _blk_cohort(w_lanes // gmask_cohorts)
-    return _pad_lanes(gmask.astype(jnp.float32), n_blocks, pad), _blk()
+        return gm, _blk_cohort(w_lanes // gmask_cohorts)
+    return gm, _blk()
 
 
 @_layout_scope
@@ -284,19 +309,19 @@ def sparsify_ef_level_pallas(g, e, mask_in, weight, tau, valid, *,
 
     Returns (ḡ [W,d] g.dtype, e' [W,d] e.dtype, nnz [W] int32); with
     ``with_err``, appends the in-kernel pinned-order ‖e'‖² ([W] f32, see
-    :func:`_pinned_tile_err`) — no separate jnp pass over e'.
+    :func:`_pinned_tile_err`) — no separate jnp pass over e'. Operands
+    given blocked (``[W, n_blocks, SUBLANES, LANES]``, see
+    :func:`to_blocks`) are read as they are and give blocked outputs.
     """
-    w_lanes, d = g.shape
-    n_blocks, pad = _geometry(d)
-    gp = _pad_lanes(g.astype(jnp.float32), n_blocks, pad)
-    ep = _pad_lanes(e.astype(jnp.float32), n_blocks, pad)
+    w_lanes, n_blocks, _ = _grid(g)
+    gp = _lanes_in(g)
+    ep = _lanes_in(e)
     has_mask = mask_in is not None
     operands = [gp, ep, weight.astype(jnp.float32), tau.astype(jnp.float32),
                 valid.astype(jnp.float32)]
     in_specs = [_blk(), _blk(), _lane(), _lane(), _lane()]
     if has_mask:
-        operands.append(_pad_lanes(mask_in.astype(jnp.float32), n_blocks,
-                                   pad))
+        operands.append(_lanes_in(mask_in))
         in_specs.append(_blk())
     out_specs = [_blk(), _blk(), _acc()]
     out_shape = [
@@ -318,8 +343,7 @@ def sparsify_ef_level_pallas(g, e, mask_in, weight, tau, valid, *,
         interpret=interpret,
     )(*operands)
     gbar, e_new, nnz = out[:3]
-    res = (_unpad(gbar, w_lanes, d), _unpad(e_new, w_lanes, d),
-           _counts(nnz))
+    res = (_lanes_out(gbar, g), _lanes_out(e_new, g), _counts(nnz))
     return res + (_lane_err(out[3]),) if with_err else res
 
 
@@ -370,18 +394,17 @@ def chain_accum_level_pallas(gamma_in, gbar, valid, gmask=None, *,
     flattened cohort-major); when given, ``nnz_off`` counts the off-mask
     support ``#{γ_out ≠ 0 ∧ m = 0}`` (the §V locally-indexed part);
     without it, ``nnz_off == nnz``.
-    Returns (γ_out [W,d], nnz [W] i32, nnz_off [W] i32).
+    Returns (γ_out [W,d], nnz [W] i32, nnz_off [W] i32); blocked operands
+    as in :func:`sparsify_ef_level_pallas`, the gmask then blocked too.
     """
-    w_lanes, d = gamma_in.shape
-    n_blocks, pad = _geometry(d)
-    gi = _pad_lanes(gamma_in.astype(jnp.float32), n_blocks, pad)
-    gb = _pad_lanes(gbar.astype(jnp.float32), n_blocks, pad)
+    w_lanes, n_blocks, _ = _grid(gamma_in)
+    gi = _lanes_in(gamma_in)
+    gb = _lanes_in(gbar)
     has_gmask = gmask is not None
     operands = [gi, gb, valid.astype(jnp.float32)]
     in_specs = [_blk(), _blk(), _lane()]
     if has_gmask:
-        op, spec = _gmask_operand(gmask, w_lanes, gmask_cohorts, n_blocks,
-                                  pad)
+        op, spec = _gmask_operand(gmask, w_lanes, gmask_cohorts)
         operands.append(op)
         in_specs.append(spec)
 
@@ -397,7 +420,7 @@ def chain_accum_level_pallas(gamma_in, gbar, valid, gmask=None, *,
         ],
         interpret=interpret,
     )(*operands)
-    return _unpad(gout, w_lanes, d), _counts(nnz), _counts(nnz_off)
+    return _lanes_out(gout, gamma_in), _counts(nnz), _counts(nnz_off)
 
 
 # ---------------------------------------------------------------------------
@@ -485,13 +508,13 @@ def cl_fuse_level_pallas(g, e, gamma_in, weight, tau, participate, valid,
     Returns (γ_out [W,d], e' [W,d], nnz [W] i32, nnz_off [W] i32) where
     ``nnz_off`` is the off-global-mask support (= nnz when gmask is None);
     with ``with_err``, appends the in-kernel pinned-order ‖e'‖² ([W] f32,
-    see :func:`_pinned_tile_err`).
+    see :func:`_pinned_tile_err`). Blocked operands as in
+    :func:`sparsify_ef_level_pallas`, the gmask then blocked too.
     """
-    w_lanes, d = g.shape
-    n_blocks, pad = _geometry(d)
-    gp = _pad_lanes(g.astype(jnp.float32), n_blocks, pad)
-    ep = _pad_lanes(e.astype(jnp.float32), n_blocks, pad)
-    gi = _pad_lanes(gamma_in.astype(jnp.float32), n_blocks, pad)
+    w_lanes, n_blocks, _ = _grid(g)
+    gp = _lanes_in(g)
+    ep = _lanes_in(e)
+    gi = _lanes_in(gamma_in)
     has_gmask = gmask is not None
     has_mask = mask_in is not None
     operands = [gp, ep, gi, weight.astype(jnp.float32),
@@ -499,13 +522,11 @@ def cl_fuse_level_pallas(g, e, gamma_in, weight, tau, participate, valid,
                 valid.astype(jnp.float32)]
     in_specs = [_blk(), _blk(), _blk(), _lane(), _lane(), _lane(), _lane()]
     if has_gmask:
-        op, spec = _gmask_operand(gmask, w_lanes, gmask_cohorts, n_blocks,
-                                  pad)
+        op, spec = _gmask_operand(gmask, w_lanes, gmask_cohorts)
         operands.append(op)
         in_specs.append(spec)
     if has_mask:
-        operands.append(_pad_lanes(mask_in.astype(jnp.float32), n_blocks,
-                                   pad))
+        operands.append(_lanes_in(mask_in))
         in_specs.append(_blk())
     out_specs = [_blk(), _blk(), _acc(), _acc()]
     out_shape = [
@@ -528,8 +549,8 @@ def cl_fuse_level_pallas(g, e, gamma_in, weight, tau, participate, valid,
         interpret=interpret,
     )(*operands)
     gout, e_new, nnz, nnz_off = out[:4]
-    res = (_unpad(gout, w_lanes, d), _unpad(e_new, w_lanes, d),
-           _counts(nnz), _counts(nnz_off))
+    res = (_lanes_out(gout, g), _lanes_out(e_new, g), _counts(nnz),
+           _counts(nnz_off))
     return res + (_lane_err(out[4]),) if with_err else res
 
 
@@ -564,12 +585,12 @@ def count_ge_level_pallas(x: jax.Array, taus: jax.Array, *,
     Per-lane brackets of the batched branch-and-bisect Top-Q threshold
     search. The zero padding's contribution is subtracted in the wrapper
     (:func:`_drop_pad_level`) — exact for any taus, including
-    non-positive ones.
+    non-positive ones. A blocked x ([W, n_blocks, SUBLANES, LANES]) is
+    counted whole: its caller's pad zeros count where taus <= 0.
     """
-    w_lanes, d = x.shape
+    w_lanes, n_blocks, pad = _grid(x)
     branch = taus.shape[-1]
-    n_blocks, pad = _geometry(d)
-    xp = _pad_lanes(x.astype(jnp.float32), n_blocks, pad)
+    xp = _lanes_in(x)
     taus = taus.astype(jnp.float32)
 
     out = pl.pallas_call(
@@ -621,21 +642,17 @@ def _fused_refs(rest, include_gamma: bool, has_gmask: bool):
 def _fused_operands(g, e, gamma_in, weight, participate, gmask, *,
                     include_gamma: bool, gmask_cohorts: int):
     """Operands and block specs shared by the fused-operand kernels."""
-    w_lanes, d = g.shape
-    n_blocks, pad = _geometry(d)
-    operands = [_pad_lanes(g.astype(jnp.float32), n_blocks, pad),
-                _pad_lanes(e.astype(jnp.float32), n_blocks, pad)]
+    w_lanes, n_blocks, pad = _grid(g)
+    operands = [_lanes_in(g), _lanes_in(e)]
     in_specs = [_blk(), _blk()]
     if include_gamma:
-        operands.append(_pad_lanes(gamma_in.astype(jnp.float32), n_blocks,
-                                   pad))
+        operands.append(_lanes_in(gamma_in))
         in_specs.append(_blk())
     operands += [weight.astype(jnp.float32),
                  participate.astype(jnp.float32)]
     in_specs += [_lane(), _lane()]
     if gmask is not None:
-        op, spec = _gmask_operand(gmask, w_lanes, gmask_cohorts, n_blocks,
-                                  pad)
+        op, spec = _gmask_operand(gmask, w_lanes, gmask_cohorts)
         operands.append(op)
         in_specs.append(spec)
     return operands, in_specs, (w_lanes, n_blocks), pad
@@ -670,7 +687,8 @@ def count_ge_fused_level_pallas(g, e, gamma_in, weight, participate, taus,
     round-trip before ``threshold_for_topq`` disappears. g, e[, γ_in]:
     [W, d]; weight, participate: [W]; taus: [W, B]; gmask per
     :func:`_gmask_operand`. Returns counts [W, B] i32; zero padding
-    reconstructs to exactly 0.0 and is subtracted in the wrapper.
+    reconstructs to exactly 0.0 and is subtracted in the wrapper. Blocked
+    operands are counted whole, as in :func:`count_ge_level_pallas`.
     """
     branch = taus.shape[-1]
     taus = taus.astype(jnp.float32)

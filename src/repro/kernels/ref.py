@@ -223,12 +223,15 @@ def fused_operand(g, e, gamma_in, weight, participate, gmask=None, *,
     * CL-TC-SIA:     ``(1−m)·(p·(w·g + e) + γ_in)``
 
     Same float expressions as the materialized jnp path in
-    ``repro.core.algorithms`` — the kernels mirror these per tile.
+    ``repro.core.algorithms`` — the kernels mirror these per tile. Inputs
+    in the kernels' block layout ([W, n_blocks, SUBLANES, LANES], the
+    gmask [n_blocks, SUBLANES, LANES]) give the operand in it.
     """
-    w = weight[:, None].astype(jnp.float32)
+    lane = (slice(None),) + (None,) * (g.ndim - 1)
+    w = weight[lane].astype(jnp.float32)
     s = w * g.astype(jnp.float32) + e.astype(jnp.float32)
     if include_gamma:
-        s = (participate[:, None].astype(jnp.float32) * s
+        s = (participate[lane].astype(jnp.float32) * s
              + gamma_in.astype(jnp.float32))
     if gmask is not None:
         gm = _expand_gmask(gmask, g.shape[0], gmask_cohorts)

@@ -36,8 +36,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.agg import compile_plan, execute
+from repro.agg import compile_plan, execute, execute_batched
 from repro.agg.device import _use_compact
+from repro.agg.plan import EXECUTOR_LAYOUTS
 from repro.core import sparsify as sp
 from repro.core.algorithms import (AggConfig, AggKind, HopStats, NodeCtx,
                                    index_bits, fused_node_steps, level_step,
@@ -122,12 +123,19 @@ def test_scalar_step_parity(kind, impl):
 # Whole-round parity through execute (level_step batched path)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("impl", IMPLS)
-@pytest.mark.parametrize("kind", ALL_KINDS)
-def test_execute_round_parity_chain_and_padded_tree(kind, impl):
-    cfg_u, cfg_f = _pair(kind, impl)
-    g, e, w = _inputs(seed=2)
-    gm = _gmask(cfg_u, D)
+# more than one kernel block with a non-zero pad: the fused round carries
+# [·, 3, 8, 1024] blocks whose last 8,115 entries per row are padding
+D_BLOCKS = 2 * 8192 + 77
+
+
+@pytest.mark.parametrize("kind,impl,d", [
+    pytest.param(kind, impl, d, id=f"{kind.value}-{impl}" + (
+        "" if d == D else f"-d{d}"))
+    for d in (D, D_BLOCKS) for kind in ALL_KINDS for impl in IMPLS])
+def test_execute_round_parity_chain_and_padded_tree(kind, impl, d):
+    cfg_u, cfg_f = _pair(kind, impl, q=11 if d == D else d // 100)
+    g, e, w = _inputs(d=d, seed=2)
+    gm = _gmask(cfg_u, d)
     part = jnp.asarray([1, 0, 1, 1, 0, 1, 1], jnp.float32)
     for name, topo, pad in [("chain", K, None), ("tree", TREE, (K, 4))]:
         plan = compile_plan(topo, pad_to=pad)
@@ -153,6 +161,33 @@ def test_execute_round_parity_dynamic_budgets(kind):
     run_f = jax.jit(functools.partial(execute, cfg_f, global_mask=gm))
     _assert_same_round(run_u(plan, g, e, w), run_f(plan, g, e, w),
                        f"{kind.value}/q_budget")
+
+
+def test_execute_batched_rows_match_blocked_rounds():
+    """``execute_batched`` keeps its [B, K, d] rows (each kernel call pads
+    them) and matches, cohort by cohort, ``execute``'s blocked round and
+    the jnp path, at a d of several kernel blocks on the padded tree."""
+    cfg_u, cfg_f = _pair(AggKind.CL_TC_SIA, "threshold", q=D_BLOCKS // 100)
+    cohorts = [_inputs(d=D_BLOCKS, seed=20 + i) for i in range(2)]
+    g, e, w = (jnp.stack(x) for x in zip(*cohorts))
+    gm = jnp.stack([_gmask(cfg_u, D_BLOCKS)] * 2)
+    part = jnp.asarray([[1, 0, 1, 1, 0, 1, 1], [1, 1, 1, 0, 1, 1, 1]],
+                       jnp.float32)
+    plan = compile_plan(TREE, pad_to=(K, 4))
+    before = EXECUTOR_LAYOUTS.copy()
+    got = jax.jit(functools.partial(execute_batched, cfg_f, global_mask=gm,
+                                    participate=part))(plan, g, e, w)
+    assert EXECUTOR_LAYOUTS == before        # no execute inside
+    want = jax.jit(functools.partial(execute_batched, cfg_u, global_mask=gm,
+                                     participate=part))(plan, g, e, w)
+    _assert_same_round(want, got, "batched")
+    run = jax.jit(lambda plan, g, e, w, m, p: execute(
+        cfg_f, plan, g, e, w, global_mask=m, participate=p))
+    for i in range(2):
+        one = run(plan, g[i], e[i], w[i], gm[i], part[i])
+        _assert_same_round(jax.tree.map(lambda x: x[i], got), one,
+                           f"cohort {i}")
+    assert EXECUTOR_LAYOUTS - before == {("blocked", 4, D_BLOCKS): 1}
 
 
 @pytest.mark.parametrize("kind", ALL_KINDS)
@@ -217,6 +252,28 @@ def test_fused_gate_trace_time():
     assert not fused_node_steps(cfg_f, w16, g, g, g)   # bf16 compute dtype
     w32 = jnp.ones((4,), jnp.float32)
     assert fused_node_steps(cfg_f, w32, g, g, g)       # promotes to f32
+
+
+def test_executor_layout_follows_the_kernel_path(monkeypatch):
+    """At the round cell's shape (a K = 8 chain over 16,132,152 entries)
+    ``execute`` carries the round in blocks when the levels run the Pallas
+    kernels and in rows on the jnp path: traced abstractly, nothing runs."""
+    import repro.kernels.ops as kops
+    k, d = 8, 16_132_152
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
+    args = (compile_plan(k), f32((k, d)), f32((k, d)), f32((k,)))
+    cfg = AggConfig(kind=AggKind.CL_SIA, q=d // 100, topq_impl="threshold")
+    seen = []
+    for mode, pallas in [("auto", True), ("never", False), ("ref", False)]:
+        before = EXECUTOR_LAYOUTS.copy()
+        with monkeypatch.context() as mp:
+            if pallas:
+                mp.setattr(kops, "resolve", lambda m: (True, False))
+            jax.eval_shape(functools.partial(
+                execute, dataclasses.replace(cfg, kernel_mode=mode)), *args)
+        seen.append(EXECUTOR_LAYOUTS - before)
+    assert seen == [{("blocked", 1, d): 1}, {("rows", 1, d): 1},
+                    {("rows", 1, d): 1}]
 
 
 def test_one_jit_trace_serves_all_same_shape_plans_fused():

@@ -17,9 +17,11 @@ these tests run: a compile for a described chip cannot be read back.
 import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -145,24 +147,35 @@ def test_unfused_round_has_no_kernels(one_chip):
 # ---------------------------------------------------------------------------
 
 SCOPED_D = 50_000       # not a whole number of kernel blocks: the operands pad
+# the round cell's shape: one chip's K = 8 chain over 16,132,152 entries
+CELL_K, CELL_D = 8, 16_132_152
 
 
-@pytest.fixture(scope="module")
-def scoped_round(one_chip):
-    """The working instructions of a K=4 CL-SIA threshold chain round with
-    the kernels on, each with its scope."""
+def _round_text(chip, k: int, d: int) -> str:
+    """The compiled text of a K-client CL-SIA threshold chain round with
+    the kernels on."""
     import repro.kernels.ops as kops
-    k, d = 4, SCOPED_D
     cfg = AggConfig(kind=AggKind.CL_SIA, q=d // 100, topq_impl="threshold")
     spec = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
-                             sharding=one_chip)
+                             sharding=chip)
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(kops, "resolve", lambda mode: (True, False))
-        text = _compile(
+        return _compile(
             lambda g, e, w, p: execute(cfg, compile_plan(k), g, e, w,
                                        participate=p),
             spec((k, d)), spec((k, d)), spec((k,)), spec((k,)))
-    return [i for i in scopes.instructions(text)
+
+
+@pytest.fixture(scope="module")
+def scoped_round_text(one_chip):
+    return _round_text(one_chip, 4, SCOPED_D)
+
+
+@pytest.fixture(scope="module")
+def scoped_round(scoped_round_text):
+    """The working instructions of a K=4 CL-SIA threshold chain round with
+    the kernels on, each with its scope."""
+    return [i for i in scopes.instructions(scoped_round_text)
             if i.executed and i.opcode != "parameter"]
 
 
@@ -177,17 +190,71 @@ def test_kernels_keep_their_names_in_their_layer(scoped_round, kernel,
     assert all(i.scope == scope and not i.inherited for i in calls)
 
 
-def test_operand_pads_are_kernel_layout(scoped_round):
-    n_blocks, pad = level._geometry(SCOPED_D)
-    assert pad > 0
-    padded = f"f32[{n_blocks * level.BLOCK}]"     # one lane: W = 1
-    pads = [i for i in scoped_round
-            if "pad" in i.name and i.shape.startswith(padded)]
-    # g, e and γ_in into each of the three count passes and into cl_fuse
-    assert len(pads) == 6
-    assert all(i.scope == scopes.KERNEL_LAYOUT and not i.inherited
-               for i in pads)
-    assert not any(i.scope is None for i in scoped_round)
+def _entries(shape: str) -> int:
+    """float32 entries of an HLO result type (a tuple's summed): the
+    round's data, not its integer counters."""
+    return sum(int(np.prod([int(n) for n in dims.split(",") if n]))
+               for dims in re.findall(r"f32\[([\d,]*)\]", shape))
+
+
+_RELAYOUT = ("pad", "slice", "reshape", "copy")
+
+
+def _is_relayout(i) -> bool:
+    """A pad, slice, reshape or layout copy, alone or at a fusion's head."""
+    head = i.name.split("_", 1)[0] if i.opcode == "fusion" else i.opcode
+    return head in _RELAYOUT
+
+
+def _assert_round_layout(text: str, d: int):
+    """The round is carried in the kernels' block layout: d-sized data is
+    padded into blocks and cut back only at the round's entry and exit,
+    under ``repro.kernel_layout``, and no one-row tile feeds a kernel."""
+    every = scopes.instructions(text)
+    shape = {i.name: i.shape for i in every}
+    entry = re.search(r"^ENTRY\s+%?([\w.\-]+)", text, re.M).group(1)
+    run = [i for i in every if i.executed and i.opcode != "parameter"]
+
+    def wide(i) -> bool:      # reads or writes d or more entries
+        return max(_entries(shape.get(n, "")) for n in
+                   (i.name,) + i.operands) >= d
+
+    # inside the level loop (every computation the entry runs): no
+    # kernel_layout op and no pad, slice, reshape or relayout copy of
+    # d-sized data; the row takes are dynamic slices of dense blocks
+    loop = [i.name for i in run if i.computation != entry and wide(i)
+            and (i.scope == scopes.KERNEL_LAYOUT or _is_relayout(i))]
+    assert not loop, loop
+    # at entry and exit: the two pads (g, e) and the two unpads (e', the
+    # aggregate) are the program's own kernel_layout ops, and the layout
+    # copies between them inherit that scope
+    edges = [i for i in run if i.computation == entry and wide(i)
+             and _is_relayout(i)]
+    own = [i for i in edges if i.opcode in ("pad", "slice")]
+    assert sorted(i.opcode for i in own) == ["pad", "pad", "slice", "slice"]
+    assert all(not i.inherited for i in own)
+    assert all(i.scope == scopes.KERNEL_LAYOUT for i in edges), \
+        [(i.name, i.scope) for i in edges]
+    # each kernel reads its d-sized operands as [W, n_blocks, 8, 1024]
+    # blocks, never as a [1, d] row in one-row tiles
+    calls = [i for i in run if i.opcode == "custom-call"
+             and "_level_pallas" in i.name]
+    assert calls
+    for i in calls:
+        rows = [shape[n] for n in i.operands if _entries(shape[n]) >= d]
+        assert rows and all(re.match(r"f32\[\d+,\d+,8,1024\]", r)
+                            and "T(1,128)" not in r for r in rows), rows
+    assert not any(i.scope is None for i in run)
+
+
+def test_operand_pads_are_kernel_layout(scoped_round_text):
+    assert level._geometry(SCOPED_D)[1] > 0
+    _assert_round_layout(scoped_round_text, SCOPED_D)
+
+
+def test_operand_pads_are_kernel_layout_at_cell_width(one_chip):
+    assert level._geometry(CELL_D)[1] > 0
+    _assert_round_layout(_round_text(one_chip, CELL_K, CELL_D), CELL_D)
 
 
 @pytest.mark.parametrize("w", [1, 8])
